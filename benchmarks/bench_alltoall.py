@@ -14,7 +14,6 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, timeit
 from repro.core import alltoall
-from repro.core.compat import shard_map
 from repro.core.alltoall import cost_flat, cost_hierarchical
 from repro.launch.mesh import parse_fabric
 
@@ -43,10 +42,10 @@ def run(paper: bool = False):
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:8]).reshape(8),
                                  ("model",))
         x = jax.random.normal(jax.random.PRNGKey(0), (64, 64, 128))
-        flat = jax.jit(shard_map(
+        flat = jax.jit(jax.shard_map(
             lambda v: alltoall.flat_all_to_all(v, "model"), mesh=mesh,
             in_specs=P("model"), out_specs=P("model"), check_vma=False))
-        hier = jax.jit(shard_map(
+        hier = jax.jit(jax.shard_map(
             lambda v: alltoall.hierarchical_all_to_all(v, "model", inner=4,
                                                        outer=2),
             mesh=mesh, in_specs=P("model"), out_specs=P("model"),

@@ -58,8 +58,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, List, Optional
 
-from repro.analysis.graph import (EqnSite, JaxprGraph, ProbeGraph,
-                                  trace_graph)
+from repro.analysis.graph import (RAGGED_DOT_PRIMITIVES, EqnSite, JaxprGraph,
+                                  ProbeGraph, trace_graph)
 from repro.analysis.hlo import HloGraph, HloOpSite
 from repro.analysis.rules import (COLLECTIVE_PRIMITIVES, DOT_PRIMITIVES,
                                   LEVELS, REGISTRY, Finding, Rule,
@@ -68,8 +68,8 @@ from repro.analysis.rules import (COLLECTIVE_PRIMITIVES, DOT_PRIMITIVES,
 __all__ = [
     "COLLECTIVE_PRIMITIVES", "DOT_PRIMITIVES", "EqnSite", "Finding",
     "HloGraph", "HloOpSite", "JaxprGraph", "LEVELS", "ProbeGraph",
-    "REGISTRY", "Rule", "lint_graph", "lint_hlo", "lint_jaxpr",
-    "lint_probe", "register", "rules_for", "run_rule", "trace_graph",
+    "RAGGED_DOT_PRIMITIVES", "REGISTRY", "Rule", "lint_graph", "lint_hlo",
+    "lint_jaxpr", "lint_probe", "register", "rules_for", "run_rule", "trace_graph",
 ]
 
 

@@ -27,7 +27,8 @@ runtime evidence (donated pytrees, ``engine.trace_counts``).
 from __future__ import annotations
 
 from collections import Counter
-from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
+from typing import (AbstractSet, Any, Dict, Iterator, List, NamedTuple,
+                    Optional, Tuple, Union)
 
 import jax
 
@@ -35,6 +36,10 @@ try:  # public home since jax 0.4.35
     from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn
 except ImportError:  # pragma: no cover - older jax
     from jax.core import ClosedJaxpr, Jaxpr, JaxprEqn  # type: ignore
+
+# ``lax.ragged_dot`` traces as ``ragged_dot_general`` on current jax and as
+# ``ragged_dot`` on older releases; rules and tests match the family.
+RAGGED_DOT_PRIMITIVES = frozenset({"ragged_dot", "ragged_dot_general"})
 
 # params whose sub-jaxpr is a LOOP BODY: entering it means the enclosed
 # eqns execute once per iteration (scan also carries a static `length`).
@@ -137,10 +142,13 @@ class JaxprGraph:
             self._sites = list(_walk(self.jaxpr, (), 0, 1))
         return self._sites
 
-    def find(self, primitive: str) -> List[EqnSite]:
-        return [s for s in self.sites() if s.primitive == primitive]
+    def find(self, primitive: Union[str, AbstractSet[str]]) -> List[EqnSite]:
+        """Sites of one primitive, or of any in a set of names (e.g.
+        :data:`RAGGED_DOT_PRIMITIVES`)."""
+        names = {primitive} if isinstance(primitive, str) else primitive
+        return [s for s in self.sites() if s.primitive in names]
 
-    def count(self, primitive: str) -> int:
+    def count(self, primitive: Union[str, AbstractSet[str]]) -> int:
         return len(self.find(primitive))
 
     def primitives(self) -> Counter:

@@ -52,7 +52,8 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from repro.analysis.graph import EqnSite, JaxprGraph, ProbeGraph
+from repro.analysis.graph import (RAGGED_DOT_PRIMITIVES, EqnSite, JaxprGraph,
+                                  ProbeGraph)
 
 LEVELS = ("error", "warn", "info")
 
@@ -67,7 +68,7 @@ COLLECTIVE_PRIMITIVES = frozenset({
 
 # dot-like primitives whose operand dtypes must agree (group_sizes /
 # index operands are integral and exempt)
-DOT_PRIMITIVES = frozenset({"dot_general", "ragged_dot"})
+DOT_PRIMITIVES = frozenset({"dot_general"}) | RAGGED_DOT_PRIMITIVES
 
 
 @dataclass(frozen=True)
@@ -412,7 +413,7 @@ def _no_recompute_backward(graph: JaxprGraph) -> List:
              "ragged_dot in a backward graph — the grouped VJP must run "
              "the Pallas dlhs/drhs kernels off the residuals, not "
              "re-derive the forward through jax.vjp(ragged_dot)")
-            for site in graph.find("ragged_dot")]
+            for site in graph.find(RAGGED_DOT_PRIMITIVES)]
 
 
 @register("dtype-leak", "error", ("jaxpr",))

@@ -64,7 +64,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import alltoall, balance, capacity, gating, layout, tuning
-from repro.core.compat import shard_map
 from repro.core.config import MoEConfig
 
 
@@ -584,7 +583,7 @@ def sharded_moe_apply(mesh: jax.sharding.Mesh, cfg: MoEConfig,
     # metric out_specs come from balance's canonical key list — a metric
     # added there must not desync this spec tree (shard_map fails with an
     # opaque pytree-mismatch error when it does)
-    y, aux, metrics = shard_map(
+    y, aux, metrics = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(param_specs, tok_spec, tok_spec, tok_spec, P()),
         out_specs=(tok_spec, P(), {k: P() for k in balance.METRIC_KEYS}),

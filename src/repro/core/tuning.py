@@ -456,11 +456,9 @@ def _measure_a2a(mesh, axis_name: str, rows: int, d: int, *,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
-
     M = mesh.shape[axis_name]
     x = jnp.zeros((M * rows, d), jnp.float32)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: alltoall.flat_all_to_all(v, axis_name), mesh=mesh,
         in_specs=P(axis_name), out_specs=P(axis_name), check_vma=False))
     jax.block_until_ready(fn(x))

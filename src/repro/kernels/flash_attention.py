@@ -32,18 +32,32 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 NEG = -1e30
 
 
 def _mask(q_pos, k_pos, causal, window):
-    m = (k_pos >= 0)[None, :]
+    """q_pos (bq, 1), k_pos (1, bk) → (bq, bk) bool."""
+    m = k_pos >= 0
     if causal:
-        m = m & (k_pos[None, :] <= q_pos[:, None])
+        m = m & (k_pos <= q_pos)
     if window is not None:
-        m = m & (k_pos[None, :] > q_pos[:, None] - window)
+        m = m & (k_pos > q_pos - window)
     return m
+
+
+def _nt(a, b):
+    """a (m, d) · b (n, d)ᵀ → (m, n) f32, no explicit transpose."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a (m, n)ᵀ · b (m, d) → (n, d) f32, no explicit transpose."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 def _fwd_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -56,26 +70,40 @@ def _fwd_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_acc[...] = jnp.full_like(m_acc, NEG)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = (q @ k.T) * scale                                 # (bq, bk)
+    q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]       # (bq|bk, d)
+    s = _nt(q, k) * scale                                 # (bq, bk) f32
     if cap is not None:
         s = cap * jnp.tanh(s / cap)
     s = jnp.where(_mask(qp_ref[...], kp_ref[...], causal, window), s, NEG)
-    m_prev = m_acc[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_acc[...]                                   # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=-1)
-    o_acc[...] = o_acc[...] * alpha[:, None] + p @ v
+    p = jnp.exp(s - m_new)
+    l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_acc[...] = o_acc[...] * alpha + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_acc[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _done():
         l = l_acc[...]
-        o_ref[0, 0] = (o_acc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (o_acc[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_acc[...] + jnp.log(l)
+
+
+def _probs_and_dscores(qp_ref, kp_ref, q, k, v, do, lse, delta, *, scale,
+                       causal, window, cap):
+    """Recompute the (bq, bk) probabilities of one block and dL/ds."""
+    s = _nt(q, k) * scale
+    if cap is not None:
+        t = jnp.tanh(s / cap)
+        s = cap * t
+    msk = _mask(qp_ref[...], kp_ref[...], causal, window)
+    p = jnp.exp(jnp.where(msk, s, NEG) - lse)             # lse (bq, 1)
+    ds = p * (_nt(do, v) - delta)
+    if cap is not None:
+        ds = ds * (1.0 - t * t)
+    return p, jnp.where(msk, ds, 0.0)
 
 
 def _bwd_dq_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -87,25 +115,13 @@ def _bwd_dq_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    s_raw = (q @ k.T) * scale
-    if cap is not None:
-        t = jnp.tanh(s_raw / cap)
-        s = cap * t
-    else:
-        s = s_raw
-    msk = _mask(qp_ref[...], kp_ref[...], causal, window)
-    s = jnp.where(msk, s, NEG)
-    p = jnp.exp(s - lse_ref[0, 0][:, None])
-    dp = do @ v.T
-    ds = p * (dp - delta_ref[0, 0][:, None])
-    if cap is not None:
-        ds = ds * (1.0 - t * t)
-    ds = jnp.where(msk, ds, 0.0)
-    dq_acc[...] += (ds @ k) * scale
+    k = k_ref[0, 0]
+    _, ds = _probs_and_dscores(
+        qp_ref, kp_ref, q_ref[0, 0], k, v_ref[0, 0], do_ref[0, 0],
+        lse_ref[0, 0], delta_ref[0, 0], scale=scale, causal=causal,
+        window=window, cap=cap)
+    dq_acc[...] += jnp.dot(ds.astype(k.dtype), k,
+                           preferred_element_type=jnp.float32) * scale
 
     @pl.when(ik == nk - 1)
     def _done():
@@ -123,26 +139,12 @@ def _bwd_dkv_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    s_raw = (q @ k.T) * scale
-    if cap is not None:
-        t = jnp.tanh(s_raw / cap)
-        s = cap * t
-    else:
-        s = s_raw
-    msk = _mask(qp_ref[...], kp_ref[...], causal, window)
-    s = jnp.where(msk, s, NEG)
-    p = jnp.exp(s - lse_ref[0, 0][:, None])              # (bq, bk)
-    dv_acc[...] += p.T @ do
-    dp = do @ v.T
-    ds = p * (dp - delta_ref[0, 0][:, None])
-    if cap is not None:
-        ds = ds * (1.0 - t * t)
-    ds = jnp.where(msk, ds, 0.0)
-    dk_acc[...] += (ds.T @ q) * scale
+    q, do = q_ref[0, 0], do_ref[0, 0]
+    p, ds = _probs_and_dscores(
+        qp_ref, kp_ref, q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0],
+        delta_ref[0, 0], scale=scale, causal=causal, window=window, cap=cap)
+    dv_acc[...] += _tn(p.astype(do.dtype), do)
+    dk_acc[...] += _tn(ds.astype(q.dtype), q) * scale
 
     @pl.when((g == ng - 1) & (iq == nq - 1))
     def _done():
@@ -178,31 +180,40 @@ def _flash_fwd(q, k, v, q_pos, k_pos, scale, causal, window, cap,
     nq, nk = Sq // bq, Sk // bk
     grid = (B, H, nq, nk)
     kv_map = lambda b, h, iq, ik: (b, h // G, ik, 0)
+    row_map = lambda b, h, iq, ik: (b, h, iq, 0)
     o, lse = _scoped(pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           window=window, cap=cap, nk=nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq,), lambda b, h, iq, ik: (iq,)),
-            pl.BlockSpec((bk,), lambda b, h, iq, ik: (ik,)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((bq, 1), lambda b, h, iq, ik: (iq, 0)),
+            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (0, ik)),
+            pl.BlockSpec((1, 1, bq, d), row_map),
             pl.BlockSpec((1, 1, bk, d), kv_map),
             pl.BlockSpec((1, 1, bk, d), kv_map),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, d), row_map),
+            pl.BlockSpec((1, 1, bq, 1), row_map),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, Sq, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu_scratch((bq, d)), pltpu_scratch((bq,)), pltpu_scratch((bq,)),
+            pltpu_scratch((bq, d)), pltpu_scratch((bq, 1)),
+            pltpu_scratch((bq, 1)),
         ],
         interpret=interpret,
-    ), q_pos, k_pos, q, k, v)
+    ), *_pos2d(q_pos, k_pos), q, k, v)
     return o, lse
+
+
+def _pos2d(q_pos, k_pos):
+    """Positions as a (Sq, 1) column and a (1, Sk) row: Mosaic tiles the
+    last two dims of every block, so 1-D position blocks do not lower."""
+    return (q_pos.astype(jnp.int32).reshape(-1, 1),
+            k_pos.astype(jnp.int32).reshape(1, -1))
 
 
 def pltpu_scratch(shape):
@@ -234,57 +245,60 @@ def _fa_bwd(scale, causal, window, cap, block_q, interpret, res, do):
     bk = _blocks(Sk, block_q)
     nq, nk = Sq // bq, Sk // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                              # (B,H,Sq)
+                    axis=-1, keepdims=True)               # (B,H,Sq,1)
+    pos = _pos2d(q_pos, k_pos)
     kv_map4 = lambda b, h, iq, ik: (b, h // G, ik, 0)
+    row_map4 = lambda b, h, iq, ik: (b, h, iq, 0)
     dq = _scoped(pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           window=window, cap=cap, nk=nk),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((bq,), lambda b, h, iq, ik: (iq,)),
-            pl.BlockSpec((bk,), lambda b, h, iq, ik: (ik,)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((bq, 1), lambda b, h, iq, ik: (iq, 0)),
+            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (0, ik)),
+            pl.BlockSpec((1, 1, bq, d), row_map4),
             pl.BlockSpec((1, 1, bk, d), kv_map4),
             pl.BlockSpec((1, 1, bk, d), kv_map4),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, d), row_map4),
+            pl.BlockSpec((1, 1, bq, 1), row_map4),
+            pl.BlockSpec((1, 1, bq, 1), row_map4),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, d), row_map4),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu_scratch((bq, d))],
         interpret=interpret,
-    ), q_pos, k_pos, q, k, v, do, lse, delta)
+    ), *pos, q, k, v, do, lse, delta)
 
     # dk/dv: grid over kv heads and blocks; accumulate across the G query
     # heads of this kv head and all q blocks
     def hmap(b, kv, ik, g, iq):
         return (b, kv * G + g, iq, 0)
 
+    kvmap5 = lambda b, kv, ik, g, iq: (b, kv, ik, 0)
+
     dk, dv = _scoped(pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, cap=cap, ng=G, nq=nq),
         grid=(B, KV, nk, G, nq),
         in_specs=[
-            pl.BlockSpec((bq,), lambda b, kv, ik, g, iq: (iq,)),
-            pl.BlockSpec((bk,), lambda b, kv, ik, g, iq: (ik,)),
+            pl.BlockSpec((bq, 1), lambda b, kv, ik, g, iq: (iq, 0)),
+            pl.BlockSpec((1, bk), lambda b, kv, ik, g, iq: (0, ik)),
             pl.BlockSpec((1, 1, bq, d), hmap),
-            pl.BlockSpec((1, 1, bk, d), lambda b, kv, ik, g, iq: (b, kv, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, kv, ik, g, iq: (b, kv, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), kvmap5),
+            pl.BlockSpec((1, 1, bk, d), kvmap5),
             pl.BlockSpec((1, 1, bq, d), hmap),
-            pl.BlockSpec((1, 1, bq), lambda b, kv, ik, g, iq: (b, kv * G + g, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b, kv, ik, g, iq: (b, kv * G + g, iq)),
+            pl.BlockSpec((1, 1, bq, 1), hmap),
+            pl.BlockSpec((1, 1, bq, 1), hmap),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, bk, d), lambda b, kv, ik, g, iq: (b, kv, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, kv, ik, g, iq: (b, kv, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), kvmap5),
+            pl.BlockSpec((1, 1, bk, d), kvmap5),
         ),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         scratch_shapes=[pltpu_scratch((bk, d)), pltpu_scratch((bk, d))],
         interpret=interpret,
-    ), q_pos, k_pos, q, k, v, do, lse, delta)
+    ), *pos, q, k, v, do, lse, delta)
     return dq, dk, dv, None, None
 
 

@@ -25,20 +25,56 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Any, NamedTuple
 
 import numpy as np
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.core import faults as faults_mod
 from repro.core import tuning
-from repro.core.config import TrainConfig
+from repro.core.config import ModelConfig, TrainConfig
 from repro.data import SyntheticLM
 from repro.launch import mesh as mesh_lib
+from repro.launch.cache import enable_compile_cache
 from repro.training import make_train_step
-from repro.training.train_step import init_train_state
+from repro.training.train_step import TrainState, init_train_state
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
+
+
+class Trainer(NamedTuple):
+    """The pieces :func:`run` drives, placed on one mesh."""
+    state: TrainState                 # sharded by mesh_lib.state_shardings
+    state_sharding: Any
+    step: Any                         # jitted (state, batch, rng) → (state, m)
+    data: SyntheticLM
+    batch_sharding: Any
+    rng: jax.Array
+
+    def batch(self, step: int):
+        """The global batch of ``step``, placed by the mesh's batch rules."""
+        return jax.device_put(self.data.next_batch(step), self.batch_sharding)
+
+
+def build(cfg: ModelConfig, tcfg: TrainConfig, mesh, *, batch: int, seq: int,
+          seed: int = 0, faults: faults_mod.FaultPlan = None) -> Trainer:
+    """Initial state, data and the jitted train step on ``mesh``.
+
+    State and batch are placed by the mesh rules from the start (experts
+    over ``model``, batch over the data axes), so nothing piles up on
+    device 0; the step keeps the state in the same shardings."""
+    rng = jax.random.PRNGKey(seed)
+    init = lambda r: init_train_state(r, cfg, tcfg)
+    state_sh = mesh_lib.state_shardings(mesh, jax.eval_shape(init, rng))
+    data = SyntheticLM(cfg, batch=batch, seq_len=seq, seed=seed)
+    step = jax.jit(make_train_step(cfg, tcfg, mesh, faults=faults),
+                   out_shardings=(state_sh, NamedSharding(mesh, P())),
+                   donate_argnums=(0,))
+    return Trainer(jax.jit(init, out_shardings=state_sh)(rng), state_sh, step,
+                   data, mesh_lib.batch_shardings(mesh, data.next_batch(0)),
+                   rng)
 
 
 def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
@@ -60,29 +96,30 @@ def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
     tmode, tfab = tuning.configure(tune, fabric, mesh=mesh)
     if cfg.moe is not None:
         print(f"tune={tmode} fabric={tfab}")
-    rng = jax.random.PRNGKey(seed)
-    state = init_train_state(rng, cfg, tcfg)
+    tr = build(cfg, tcfg, mesh, batch=batch, seq=seq, seed=seed,
+               faults=faults)
+    state, rng = tr.state, tr.rng
     start = 0
     if resume:
         if latest_step(ckpt_dir) is not None:
             state, start = restore_checkpoint(ckpt_dir, state)
+            state = jax.device_put(state, tr.state_sharding)
             print(f"resumed from step {start} ({ckpt_dir})")
         else:
             print(f"--resume: no checkpoint under {ckpt_dir}, starting fresh")
     n_params = sum(np.prod(p.shape) for p in jax.tree.leaves(state.params))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M mesh={dict(mesh.shape)}")
-    ds = SyntheticLM(cfg, batch=batch, seq_len=seq, seed=seed)
-    step_fn = jax.jit(make_train_step(cfg, tcfg, mesh, faults=faults),
-                      donate_argnums=(0,))
     history = []
     t0 = time.time()
     with faults_mod.active(faults):
         for s in range(start, steps):
             faults_mod.crash_point("train.loop", index=s)
-            bt = ds.next_batch(s)
-            state, m = step_fn(state, bt, jax.random.fold_in(rng, s))
+            t_step = time.perf_counter()
+            state, m = tr.step(state, tr.batch(s), jax.random.fold_in(rng, s))
             m = {k: float(v) for k, v in m.items()}
-            history.append({"step": s, **m})
+            # host seconds to the step's metrics (float() waits for them)
+            history.append({"step": s, **m,
+                            "step_s": time.perf_counter() - t_step})
             if s % log_every == 0 or s == steps - 1:
                 dt = time.time() - t0
                 tput = batch * seq * (s + 1 - start) / max(dt, 1e-9)
@@ -152,6 +189,7 @@ def main():
                     help="named fast/slow LinkSpec pair the tuner scores "
                          "against (ici_dcn | pcie_eth100)")
     args = ap.parse_args()
+    enable_compile_cache()
     faults = faults_mod.plan_from_specs(args.inject) if args.inject else None
     run(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         smoke=args.smoke, lr=args.lr, microbatches=args.microbatches,

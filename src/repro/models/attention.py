@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.compat import shard_map
 from repro.core.config import AttentionConfig
 from repro.models.layers import rms_norm, softcap as _softcap
 
@@ -159,13 +158,14 @@ def _flash_path(q, k, v, positions, mesh, *, causal, window, cap, scale,
     against the full k/v — GQA-agnostic, divides for every arch), batch
     over the data axes; k/v replicate over `model` (gathered ONCE at the
     shard_map boundary — the fix per-chunk staging couldn't achieve)."""
+    from repro.kernels import ops as kops
     from repro.kernels.flash_attention import flash_attention
     B, S, H, hd = q.shape
     qh = q.transpose(0, 2, 1, 3)                   # (B, H, S, hd)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     k_pos = positions
-    interpret = jax.default_backend() != "tpu"
+    interpret = kops.INTERPRET
     msize = mesh.shape.get("model", 1) if mesh is not None else 1
     if mesh is None or mesh.devices.size == 1 or S % msize or msize <= 1:
         o = flash_attention(qh, kh, vh, positions, k_pos, scale, causal,
@@ -178,7 +178,7 @@ def _flash_path(q, k, v, positions, mesh, *, causal, window, cap, scale,
         return flash_attention(qh, kh, vh, qp, kp, scale, causal, window,
                                cap, min(q_chunk, 512), interpret)
 
-    o = shard_map(
+    o = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, "model", None), P(dp, None, None, None),
                   P(dp, None, None, None), P("model"), P(None)),

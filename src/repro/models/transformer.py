@@ -32,7 +32,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import moe as moe_lib
-from repro.core.compat import shard_map
 from repro.core.config import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import layers, mamba2, rwkv6
@@ -330,7 +329,7 @@ def _embed_inputs(params, cfg: ModelConfig, inputs: jax.Array, dtype, mesh=None)
             rows = tbl.astype(dtype)[jnp.clip(rel, 0, vloc - 1)]
             return lax.psum(jnp.where(ok[..., None], rows, 0), "model")
 
-        x = shard_map(
+        x = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("model", None), P(dp)),
             out_specs=P(dp, None, None), check_vma=False,

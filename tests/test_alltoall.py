@@ -7,14 +7,13 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import alltoall
-from repro.core.compat import shard_map
 
 RNG = jax.random.PRNGKey(2)
 
 
 def _run(mesh_model8, fn):
-    return jax.jit(shard_map(fn, mesh=mesh_model8, in_specs=P("model"),
-                                 out_specs=P("model"), check_vma=False))
+    return jax.jit(jax.shard_map(fn, mesh=mesh_model8, in_specs=P("model"),
+                                     out_specs=P("model"), check_vma=False))
 
 
 @pytest.mark.parametrize("inner,outer", [(2, 4), (4, 2), (8, 1), (1, 8)])
@@ -39,7 +38,7 @@ def test_hierarchical_gradient(mesh_model8):
     x = jax.random.normal(RNG, (64, 4, 8))
 
     def loss(v):
-        out = shard_map(
+        out = jax.shard_map(
             lambda u: alltoall.hierarchical_all_to_all(u, "model", inner=4,
                                                        outer=2),
             mesh=mesh_model8, in_specs=P("model"), out_specs=P("model"),
@@ -142,7 +141,7 @@ def test_grouped_a2a_counts_and_tokens_land_source_major(mesh_model8, mode,
         return alltoall.grouped_all_to_all(v, c, "model", mode=mode,
                                            inner=inner)
 
-    recv_x, recv_c = jax.jit(shard_map(
+    recv_x, recv_c = jax.jit(jax.shard_map(
         fn, mesh=mesh_model8, in_specs=(P("model"), P("model")),
         out_specs=(P("model"), P("model")), check_vma=False))(x, counts)
     # global views: sender s's chunk for dest r is x[s*M + r]
@@ -161,7 +160,7 @@ def test_grouped_a2a_gradient(mesh_model8):
     counts = jnp.ones((64, 2), jnp.int32)
 
     def loss(v):
-        out, _ = shard_map(
+        out, _ = jax.shard_map(
             lambda u, c: alltoall.grouped_all_to_all(
                 u, c, "model", mode="hierarchical", inner=4),
             mesh=mesh_model8, in_specs=(P("model"), P("model")),
@@ -247,7 +246,7 @@ def test_quantized_exchange_matches_unquantized(mesh_model8, qdt, mode,
         M * M, E_local)
 
     def run(f):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh_model8, in_specs=(P("model"), P("model")),
             out_specs=(P("model"), P("model")), check_vma=False))(x, counts)
 
@@ -292,7 +291,7 @@ def test_quantized_exchange_gradient_is_quantized_involution(mesh_model8):
     counts = jnp.ones((64, 2), jnp.int32)
 
     def loss(v):
-        out, _ = shard_map(
+        out, _ = jax.shard_map(
             lambda u, c: alltoall.quantized_exchange(
                 u, c, "model", mode="hierarchical", inner=4,
                 payload_dtype="int8"),

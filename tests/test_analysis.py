@@ -76,7 +76,6 @@ def test_trace_graph_context_and_primitives_counter():
 def test_known_bad_collective_in_loop_jaxpr(mesh_ep4):
     """The PR 5 anti-pattern: pipelining via fori_loop/scan folds every
     exchange into ONE loop-body collective."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def layer(x):          # x (4, d) local block, scans 4 "chunks"
@@ -85,8 +84,8 @@ def test_known_bad_collective_in_loop_jaxpr(mesh_ep4):
         c, _ = jax.lax.scan(body, jnp.zeros(x.shape[-1]), x)
         return c
 
-    fn = shard_map(layer, mesh=mesh_ep4, in_specs=P(None, None),
-                   out_specs=P(None), check_rep=False)
+    fn = jax.shard_map(layer, mesh=mesh_ep4, in_specs=P(None, None),
+                       out_specs=P(None), check_vma=False)
     g = analysis.trace_graph(fn, jnp.ones((4, 8)))
     findings = analysis.run_rule("collective-in-loop", g)
     assert len(findings) == 1

@@ -6,25 +6,9 @@ import pytest
 from hypothesis_compat import hypothesis, st
 
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ref import ref_flash_attention as ref
 
 RNG = jax.random.PRNGKey(11)
-
-
-def ref(q, k, v, q_pos, k_pos, scale, causal, window, cap):
-    G = q.shape[1] // k.shape[1]
-    kk = jnp.repeat(k, G, axis=1).astype(jnp.float32)
-    vv = jnp.repeat(v, G, axis=1).astype(jnp.float32)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kk) * scale
-    if cap is not None:
-        s = cap * jnp.tanh(s / cap)
-    m = (k_pos >= 0)[None, :]
-    if causal:
-        m = m & (k_pos[None, :] <= q_pos[:, None])
-    if window is not None:
-        m = m & (k_pos[None, :] > q_pos[:, None] - window)
-    s = jnp.where(m[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vv).astype(q.dtype)
 
 
 @pytest.mark.parametrize("B,H,KV,S,d,causal,win,cap", [

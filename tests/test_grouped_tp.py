@@ -56,7 +56,7 @@ def test_grouped_tp_runs_grouped_path_not_sort(mesh8):
         lambda p_, v: moe.sharded_moe_apply(mesh8, cfg, p_, v, num_experts=E,
                                             act="swiglu",
                                             expert_tp_axis="data"), p, x)
-    assert g.count("ragged_dot") > 0
+    assert g.count(analysis.RAGGED_DOT_PRIMITIVES) > 0
     # and the TP collectives surround it (gather the segments, reduce
     # the f-contraction) — the capacity-padded (E·C) buffer path would
     # show neither with these shapes

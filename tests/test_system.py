@@ -69,8 +69,6 @@ def test_mini_dryrun_lowers_and_compiles(mesh8):
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(state, batch, rng).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):          # jax 0.4.x: one dict per device
-        ca = ca[0]
     assert ca.get("flops", 0) > 0
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes > 0
