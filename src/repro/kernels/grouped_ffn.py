@@ -67,13 +67,13 @@ DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 512
 
 
-def _lane_tile(n: int, want: int = DEFAULT_BLOCK_N) -> int:
+def _lane_tile(n: int) -> int:
     """Largest multiple of 128 that divides ``n`` and is at most
-    ``want``; ``n`` itself when it is that small or has no such divisor
-    (a block equal to the full dim always lowers)."""
-    if n <= want:
+    ``DEFAULT_BLOCK_N``; ``n`` itself when it is that small or has no such
+    divisor (a block equal to the full dim always lowers)."""
+    if n <= DEFAULT_BLOCK_N:
         return n
-    for t in range(want - want % 128, 0, -128):
+    for t in range(DEFAULT_BLOCK_N, 0, -128):
         if n % t == 0:
             return t
     return n

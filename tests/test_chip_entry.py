@@ -8,6 +8,7 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 from repro.launch import cache
@@ -52,6 +53,27 @@ def test_smoke_device_check_refuses_cpu():
     smoke = _load_smoke()
     with pytest.raises(smoke.SmokeFailure, match="needs a TPU"):
         smoke.check_device()
+
+
+def test_smoke_expert_check_finds_a_misplaced_tile():
+    """The expert-gradient check keeps only the expert weights, and names
+    the one (layer, expert) slice with a (512, 512) tile in the wrong
+    place."""
+    smoke = _load_smoke()
+    rng = np.random.RandomState(0)
+    w_up = rng.randn(2, 4, 1024, 1024).astype(np.float32)
+    opt = {"m": {"blocks": [{"moe": {"w_up": w_up,
+                                     "gate_w": np.ones((2, 1024, 4))},
+                             "mlp": {"w_out": np.ones((1024, 8))}}],
+                 "embed": np.ones((8, 1024))}}
+    want = smoke.expert_grads(opt)
+    assert list(want) == ["['blocks'][0]['moe']['w_up']"]
+    got = {k: v.copy() for k, v in want.items()}
+    tile = got["['blocks'][0]['moe']['w_up']"][1, 2]
+    tile[:512, 512:] = tile[512:, :512]
+    err, worst = smoke.worst_expert(got, want)
+    assert worst == "['blocks'][0]['moe']['w_up'][1, 2]" and err > 0.25
+    assert smoke.worst_expert(want, want)[0] == 0.0
 
 
 @pytest.mark.parametrize("where", ["checkout", "alone"])

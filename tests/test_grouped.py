@@ -471,6 +471,7 @@ def test_grouped_ep_receive_maps_invert():
     (8, 5, 16, 128),       # M < block_m
     (3, 200, 8, 64),       # tiny source, many rows
     (33, 130, 8, 128),     # one full + one ragged block
+    (40, 37, 256, 8),      # d = 2·128: rows move as (d/128, 128) tiles
 ])
 def test_blocked_gather_bit_identical(N, M, d, bm):
     key = jax.random.PRNGKey(N * M)
@@ -482,7 +483,8 @@ def test_blocked_gather_bit_identical(N, M, d, bm):
 
 
 @pytest.mark.parametrize("N,M,d,bm", [
-    (64, 64, 32, 16), (16, 37, 8, 8), (8, 5, 16, 128)])
+    (64, 64, 32, 16), (16, 37, 8, 8), (8, 5, 16, 128),
+    (16, 37, 256, 8)])      # d = 2·128: rows move as (d/128, 128) tiles
 def test_blocked_scatter_add_matches_jnp(N, M, d, bm):
     key = jax.random.PRNGKey(M)
     g = jax.random.normal(key, (M, d))
@@ -512,15 +514,23 @@ def test_blocked_gather_vjp_uses_blocked_scatter():
 # grouped matmul kernel vs lax.ragged_dot
 # ---------------------------------------------------------------------------
 
+def _small_ints(key, shape):
+    """Integers in [-4, 4] as f32: every product and partial sum of these
+    contractions is exact, so the kernels and ragged_dot agree whatever
+    their summation order, even at K = 1024."""
+    return jax.random.randint(key, shape, -4, 5).astype(jnp.float32)
+
+
 @pytest.mark.parametrize("M,K,N,E,bm,tail", [
     (64, 16, 24, 4, 16, 0),
     (100, 8, 8, 3, 128, 7),     # virtual-bucket tail rows → zeros
     (37, 32, 16, 5, 8, 3),      # ragged blocks + tail
+    (64, 1024, 1536, 4, 16, 5),  # 2 x 3 lane tiles of 512 (K != N)
 ])
 def test_grouped_matmul_matches_ragged_dot(M, K, N, E, bm, tail):
     k1, k2 = jax.random.split(jax.random.PRNGKey(M), 2)
-    lhs = jax.random.normal(k1, (M, K))
-    rhs = jax.random.normal(k2, (E, K, N))
+    lhs = _small_ints(k1, (M, K))
+    rhs = _small_ints(k2, (E, K, N))
     total = M - tail
     cuts = np.sort(np.random.RandomState(0).randint(0, total + 1, E - 1))
     sizes = jnp.array(np.diff(np.concatenate([[0], cuts, [total]])),
@@ -563,14 +573,21 @@ def test_grouped_ffn_paths_agree():
 @pytest.mark.parametrize("dtype,rtol,atol", [
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 3e-2)])
 @pytest.mark.parametrize("bm", [16, 128])
-def test_grouped_bwd_matches_ragged_vjp(dtype, rtol, atol, bm):
-    """Explicit-cotangent VJP equality, with an EMPTY expert segment
-    (expert 2) and 6 drop-bucket tail rows past offsets[-1]."""
-    M, K, N, E = 96, 16, 24, 5
-    lhs = jax.random.normal(RNG, (M, K)).astype(dtype)
-    rhs = jax.random.normal(jax.random.PRNGKey(1), (E, K, N)).astype(dtype)
-    sizes = jnp.array([30, 20, 0, 25, 15], jnp.int32)      # Σ = 90 < 96
-    g = jax.random.normal(jax.random.PRNGKey(2), (M, N)).astype(dtype)
+@pytest.mark.parametrize("M,K,N,group_sizes", [
+    (96, 16, 24, (30, 20, 0, 25, 15)),
+    # K = 1024, N = 1536: dlhs over 2 and drhs over 2 x 3 lane tiles of 512
+    (64, 1024, 1536, (20, 0, 26, 12)),
+], ids=["narrow", "lane_tiled"])
+def test_grouped_bwd_matches_ragged_vjp(M, K, N, group_sizes, dtype, rtol,
+                                        atol, bm):
+    """Explicit-cotangent VJP equality, with an EMPTY expert segment and
+    6 drop-bucket tail rows past offsets[-1]."""
+    E, total = len(group_sizes), sum(group_sizes)
+    empty = group_sizes.index(0)
+    lhs = _small_ints(RNG, (M, K)).astype(dtype)
+    rhs = _small_ints(jax.random.PRNGKey(1), (E, K, N)).astype(dtype)
+    sizes = jnp.array(group_sizes, jnp.int32)              # Σ = M - 6
+    g = _small_ints(jax.random.PRNGKey(2), (M, N)).astype(dtype)
 
     _, vjp_p = jax.vjp(lambda l, r: grouped_matmul(l, r, sizes, True, bm),
                        lhs, rhs)
@@ -585,9 +602,9 @@ def test_grouped_bwd_matches_ragged_vjp(dtype, rtol, atol, bm):
                                rtol=rtol, atol=atol)
     assert dl_p.dtype == lhs.dtype and dr_p.dtype == rhs.dtype
     # tail rows produce zero output, so their lhs gradient is zero
-    assert np.allclose(np.asarray(dl_p, np.float32)[90:], 0.0)
+    assert np.allclose(np.asarray(dl_p, np.float32)[total:], 0.0)
     # an empty expert's weight gradient is exactly zero
-    assert np.allclose(np.asarray(dr_p, np.float32)[2], 0.0)
+    assert np.allclose(np.asarray(dr_p, np.float32)[empty], 0.0)
 
 
 def test_grouped_bwd_is_pallas_not_ragged_recompute():
