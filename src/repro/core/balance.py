@@ -17,7 +17,7 @@ from repro.core.gating import GateOutput
 # every consumer stays in sync; duplicating the names at the shard_map
 # boundary produced an opaque pytree-mismatch error instead.
 METRIC_KEYS = ("load_balance_loss", "router_z_loss",
-               "expert_load_max", "expert_load_min")
+               "expert_load_max", "dropped_share")
 
 
 def _masked_mean(x: jax.Array, valid: Optional[jax.Array],
@@ -79,6 +79,7 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
                expert_counts: jax.Array | None = None,
                valid: Optional[jax.Array] = None,
                axes: Tuple[str, ...] = (),
+               dropped: Optional[jax.Array] = None,
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Weighted aux-loss scalar + router metrics dict.
 
@@ -87,7 +88,14 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
     one-hot re-count here (sort-once: the plan is the source of truth for
     load state).  ``valid`` (S,) — mask of real (non-padded) tokens;
     ``axes`` — mesh axes to reduce over, making lb/z-loss exact GLOBAL
-    masked means (the caller's later pmean is then an identity on them).
+    masked means and the load metrics global counts (the caller's later
+    pmean is then an identity on them).  ``dropped`` — how many of this
+    shard's valid assignments the dispatch dropped (capacity or the
+    grouped-EP bound); ``None`` for a dropless dispatch.
+
+    Metrics: ``expert_load_max`` is the busiest expert's share of all
+    routed assignments (1/E when balanced, 1 when collapsed);
+    ``dropped_share`` is the dropped assignments over all routed ones.
     """
     E = gate.router_probs.shape[-1]
     lb = load_balance_loss(gate, valid, axes)
@@ -98,10 +106,14 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
     else:
         counts = jnp.sum(
             jax.nn.one_hot(gate.expert_index, E, dtype=jnp.float32), axis=(0, 1))
+    dropped = (jnp.zeros((), jnp.float32) if dropped is None
+               else dropped.astype(jnp.float32))
+    if axes:
+        counts, dropped = lax.psum((counts, dropped), axes)
     total = jnp.maximum(jnp.sum(counts), 1.0)
     # zip(strict=True) raises even under ``python -O`` if a metric is
     # added to one side but not the other
     metrics = dict(zip(METRIC_KEYS,
-                       (lb, zl, jnp.max(counts) / total,
-                        jnp.min(counts) / total), strict=True))
+                       (lb, zl, jnp.max(counts) / total, dropped / total),
+                       strict=True))
     return loss, metrics

@@ -143,14 +143,21 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
     E_local = E // model_size
     assert params["w_up"].shape[0] == E_local, (params["w_up"].shape, E_local)
 
+    # Each stage runs under one flat named scope (moe_gate, moe_layout,
+    # moe_exchange, moe_experts), so the compiled ops' op_name metadata,
+    # forward and backward, says which stage a device op belongs to.
+
     # -- 1. gate ----------------------------------------------------------
-    logits = gating.router_logits(cfg, x, params["gate_w"])
-    gate = gating.route(cfg, logits, rng=rng, token_ids=token_ids)
-    if valid is not None:
-        # padded tokens → virtual expert E: dropped by the plan, zero weight
-        gate = gate._replace(
-            expert_index=jnp.where(valid[:, None], gate.expert_index, E),
-            combine_weights=jnp.where(valid[:, None], gate.combine_weights, 0.0))
+    with jax.named_scope("moe_gate"):
+        logits = gating.router_logits(cfg, x, params["gate_w"])
+        gate = gating.route(cfg, logits, rng=rng, token_ids=token_ids)
+        if valid is not None:
+            # padded tokens → virtual expert E: dropped by the plan, zero
+            # weight
+            gate = gate._replace(
+                expert_index=jnp.where(valid[:, None], gate.expert_index, E),
+                combine_weights=jnp.where(valid[:, None],
+                                          gate.combine_weights, 0.0))
 
     # -- 2. dispatch plan (ONE sort; aux metrics reuse its counts) ----------
     dispatch = cfg.dispatch
@@ -159,28 +166,36 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
     if dispatch == "grouped":
         # dropless: expert-sorted (T·K, d) buffer, no capacity padding;
         # the expert FFN runs as grouped/ragged matmuls over the segments.
-        gplan = layout.plan_grouped(gate, E, drop_bucket=True)
-        aux, metrics = balance.aux_losses(cfg, gate,
-                                          expert_counts=gplan.counts,
-                                          valid=valid, axes=pmean_axes)
         from repro.kernels import grouped_ffn as gffn
         from repro.kernels import ops as kops
         gather = kops.gather_rows if cfg.use_pallas_gate else layout.take_rows
-        if model_size > 1:
-            # grouped AllToAll (dropless EP): the expert-sorted buffer is
-            # destination-rank-sorted too, so dispatch is one gather into
-            # the static (M, B, d) exchange layout; counts cross first so
-            # the receive side can rebuild its ragged offsets.
-            B = capacity.grouped_segment_bound(cfg, T, model_size)
-            eplan = layout.plan_grouped_ep(gplan, E, model_size, B)
-            packed = gather(x, eplan.pack_map).reshape(model_size, B, d)
-            send_counts = eplan.send_counts            # (M, E_local)
-        else:
-            B = capacity.grouped_tp_gather_bound(cfg, T)
-            xs0 = (gather(x, gplan.token) if cfg.use_pallas_gate
-                   else layout.dispatch_grouped(x, gplan))
-            packed = xs0.reshape(1, B, d)              # the sorted buffer
-            send_counts = gplan.counts[None]           # (1, E)
+        with jax.named_scope("moe_layout"):
+            gplan = layout.plan_grouped(gate, E, drop_bucket=True)
+            if model_size > 1:
+                # grouped AllToAll (dropless EP): the expert-sorted buffer
+                # is destination-rank-sorted too, so dispatch is one
+                # gather into the static (M, B, d) exchange layout; counts
+                # cross first so the receive side can rebuild its ragged
+                # offsets.
+                B = capacity.grouped_segment_bound(cfg, T, model_size)
+                eplan = layout.plan_grouped_ep(gplan, E, model_size, B)
+                packed = gather(x, eplan.pack_map).reshape(model_size, B, d)
+                send_counts = eplan.send_counts        # (M, E_local)
+                # assignments past a destination rank's bound
+                dropped = (jnp.sum(gplan.counts)
+                           - jnp.sum(eplan.send_counts))
+            else:
+                B = capacity.grouped_tp_gather_bound(cfg, T)
+                xs0 = (gather(x, gplan.token) if cfg.use_pallas_gate
+                       else layout.dispatch_grouped(x, gplan))
+                packed = xs0.reshape(1, B, d)          # the sorted buffer
+                send_counts = gplan.counts[None]       # (1, E)
+                dropped = None                         # B = T·K: dropless
+        with jax.named_scope("moe_gate"):
+            aux, metrics = balance.aux_losses(cfg, gate,
+                                              expert_counts=gplan.counts,
+                                              valid=valid, axes=pmean_axes,
+                                              dropped=dropped)
         n_src = packed.shape[0]
         # Wire dtype for the exchange payloads (MegaScale-MoE).  A no-op
         # without expert parallelism: the exchange is the identity, so
@@ -196,13 +211,14 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
             at the compute dtype — the downstream TP gather / row maps /
             grouped matmuls are unchanged."""
             if model_size > 1:
-                if qdt is not None:
-                    return alltoall.quantized_exchange(
-                        chunk, counts, model_axis, mode=cfg.a2a,
-                        inner=cfg.a2a_inner, payload_dtype=qdt)
-                return alltoall.grouped_all_to_all(
-                    chunk, counts, model_axis,
-                    mode=cfg.a2a, inner=cfg.a2a_inner)
+                with jax.named_scope("moe_exchange"):
+                    if qdt is not None:
+                        return alltoall.quantized_exchange(
+                            chunk, counts, model_axis, mode=cfg.a2a,
+                            inner=cfg.a2a_inner, payload_dtype=qdt)
+                    return alltoall.grouped_all_to_all(
+                        chunk, counts, model_axis,
+                        mode=cfg.a2a, inner=cfg.a2a_inner)
             return chunk, counts
 
         def compute(recv, counts, bc):
@@ -216,49 +232,58 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
                 # ranks — the bound derives from static shapes only, see
                 # capacity.grouped_tp_gather_bound), merge into one shared
                 # expert-major order, and run this rank's f-slice.
-                recv = lax.all_gather(recv, tp, axis=0, tiled=True)
-                counts = lax.all_gather(counts, tp, axis=0, tiled=True)
+                with jax.named_scope("moe_exchange"):
+                    recv = lax.all_gather(recv, tp, axis=0, tiled=True)
+                    counts = lax.all_gather(counts, tp, axis=0, tiled=True)
             # the gathered chunk count is R·M by all_gather construction
             # (1 with neither TP nor EP) — the merged maps key off it
             n_chunks = recv.shape[0]
             if model_size > 1 or tp is not None:
-                ffn_src, dst_map, group_sizes = layout.grouped_tp_gather_maps(
-                    counts, bc)
-                xs = gather(recv.reshape(n_chunks * bc, d), ffn_src)
+                with jax.named_scope("moe_layout"):
+                    ffn_src, dst_map, group_sizes = (
+                        layout.grouped_tp_gather_maps(counts, bc))
+                    xs = gather(recv.reshape(n_chunks * bc, d), ffn_src)
             else:
                 xs = recv.reshape(bc, d)
                 group_sizes = counts[0]
-            ys = gffn.grouped_ffn(params, xs.astype(params["w_up"].dtype),
-                                  group_sizes, act,
-                                  use_pallas=cfg.use_pallas_gate,
-                                  interpret=kops.INTERPRET,
-                                  block_m=(cfg.grouped_block_m
-                                           or gffn.DEFAULT_BLOCK_M))
+            with jax.named_scope("moe_experts"):
+                ys = gffn.grouped_ffn(params,
+                                      xs.astype(params["w_up"].dtype),
+                                      group_sizes, act,
+                                      use_pallas=cfg.use_pallas_gate,
+                                      interpret=kops.INTERPRET,
+                                      block_m=(cfg.grouped_block_m
+                                               or gffn.DEFAULT_BLOCK_M))
             if tp is not None:
                 # back to chunk layout, then reduce the f-contraction
                 # while scattering each TP rank its own rows (tiled:
                 # chunk r of the summed (R·M·bc, d) array is rank r's
                 # (M·bc, d) layout)
-                h = gather(ys, dst_map)
-                ys = lax.psum_scatter(h, tp, scatter_dimension=0,
-                                      tiled=True)
+                with jax.named_scope("moe_layout"):
+                    h = gather(ys, dst_map)
+                with jax.named_scope("moe_exchange"):
+                    ys = lax.psum_scatter(h, tp, scatter_dimension=0,
+                                          tiled=True)
             if model_size > 1:
                 # expert-major FFN rows → exchange layout → AllToAll home
-                h = (ys.reshape(model_size, bc, d) if tp is not None
-                     else gather(ys, dst_map).reshape(model_size, bc, d))
-                if qdt is not None:
-                    # combine payload quantized like dispatch (the scales
-                    # go over their own tiny flat exchange — no count
-                    # matrix travels this direction) and dequantized to
-                    # f32, so the weighted combine reduction below runs
-                    # in f32 regardless of the compute dtype.
-                    out, _ = alltoall.quantized_exchange(
-                        h, None, model_axis, mode=cfg.a2a,
-                        inner=cfg.a2a_inner, payload_dtype=qdt,
-                        out_dtype=jnp.float32)
-                    return out
-                return alltoall.all_to_all(h, model_axis, mode=cfg.a2a,
-                                           inner=cfg.a2a_inner)
+                with jax.named_scope("moe_layout"):
+                    h = (ys.reshape(model_size, bc, d) if tp is not None
+                         else gather(ys, dst_map).reshape(model_size, bc, d))
+                with jax.named_scope("moe_exchange"):
+                    if qdt is not None:
+                        # combine payload quantized like dispatch (the
+                        # scales go over their own tiny flat exchange —
+                        # no count matrix travels this direction) and
+                        # dequantized to f32, so the weighted combine
+                        # reduction below runs in f32 regardless of the
+                        # compute dtype.
+                        out, _ = alltoall.quantized_exchange(
+                            h, None, model_axis, mode=cfg.a2a,
+                            inner=cfg.a2a_inner, payload_dtype=qdt,
+                            out_dtype=jnp.float32)
+                        return out
+                    return alltoall.all_to_all(h, model_axis, mode=cfg.a2a,
+                                               inner=cfg.a2a_inner)
             return ys.reshape(1, bc, d)
 
         n_overlap = cfg.overlap_chunks
@@ -274,8 +299,9 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
             # collective, hiding the pipeline from the scheduler (and
             # from the jaxpr witness tests).
             Bc = capacity.grouped_overlap_chunk_bound(cfg, B)
-            chunk_counts = layout.grouped_chunk_counts(
-                send_counts, B, n_overlap)             # (P, n_src, E_seg)
+            with jax.named_scope("moe_layout"):
+                chunk_counts = layout.grouped_chunk_counts(
+                    send_counts, B, n_overlap)         # (P, n_src, E_seg)
             windows = packed.reshape(n_src, n_overlap, Bc, d)
             recv, rcounts = exchange(windows[:, 0], chunk_counts[0])
             outs = []
@@ -290,43 +316,52 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
         else:
             out = compute(*exchange(packed, send_counts), B)
 
-        if model_size > 1:
-            # reverse path: combined exchange layout → this rank's
-            # sorted rows → weighted combine
-            ys = gather(out.reshape(model_size * B, d), eplan.back_map)
-        else:
-            ys = out.reshape(B, d)
-        y = layout.combine_grouped(ys, gplan, T)
+        with jax.named_scope("moe_layout"):
+            if model_size > 1:
+                # reverse path: combined exchange layout → this rank's
+                # sorted rows → weighted combine
+                ys = gather(out.reshape(model_size * B, d), eplan.back_map)
+            else:
+                ys = out.reshape(B, d)
+            y = layout.combine_grouped(ys, gplan, T)
         if pmean_axes:
-            aux = lax.pmean(aux, pmean_axes)
-            metrics = {k: lax.pmean(v, pmean_axes) for k, v in metrics.items()}
+            with jax.named_scope("moe_gate"):
+                aux = lax.pmean(aux, pmean_axes)
+                metrics = {k: lax.pmean(v, pmean_axes)
+                           for k, v in metrics.items()}
         return y.astype(x.dtype), aux, metrics
 
     C = capacity.expert_capacity(cfg, T, E)
-    if dispatch == "sort":
-        plan = layout.plan_sort(gate, E, C, drop_bucket=True)
-        if cfg.use_pallas_gate:
-            # the blocked Pallas layout kernel replaces the jnp gather on
-            # TPU, driven by the plan's sort-derived inverse row map;
-            # interpret-mode equivalence is asserted in tests
-            from repro.kernels import ops as kops
-            buf = kops.layout_dispatch(x, plan.slot, E, C, inv=plan.inv)
+    with jax.named_scope("moe_layout"):
+        if dispatch == "sort":
+            plan = layout.plan_sort(gate, E, C, drop_bucket=True)
+            if cfg.use_pallas_gate:
+                # the blocked Pallas layout kernel replaces the jnp gather
+                # on TPU, driven by the plan's sort-derived inverse row
+                # map; interpret-mode equivalence is asserted in tests
+                from repro.kernels import ops as kops
+                buf = kops.layout_dispatch(x, plan.slot, E, C, inv=plan.inv)
+            else:
+                buf = layout.dispatch_scatter(x, plan, E, C)
         else:
-            buf = layout.dispatch_scatter(x, plan, E, C)
-    else:
-        plan = layout.plan_cumsum(gate, E, C, drop_bucket=True)
-        buf = layout.dispatch_dense(x, plan, E, C)
-    aux, metrics = balance.aux_losses(cfg, gate, expert_counts=plan.counts,
-                                      valid=valid, axes=pmean_axes)
+            plan = layout.plan_cumsum(gate, E, C, drop_bucket=True)
+            buf = layout.dispatch_dense(x, plan, E, C)
+    with jax.named_scope("moe_gate"):
+        # the plan's counts are pre-capacity: past C an expert drops
+        dropped = jnp.sum(jnp.maximum(plan.counts - C, 0))
+        aux, metrics = balance.aux_losses(cfg, gate, expert_counts=plan.counts,
+                                          valid=valid, axes=pmean_axes,
+                                          dropped=dropped)
 
     # -- 3. AllToAll (dispatch) ---------------------------------------------
     if model_size > 1:
-        buf = buf.reshape(model_size, E_local * C, d)
-        buf = alltoall.all_to_all(buf, model_axis, mode=cfg.a2a,
-                                  inner=cfg.a2a_inner)
-        # (M, E_local·C, d) source-major → (E_local, M·C, d)
-        buf = (buf.reshape(model_size, E_local, C, d)
-               .transpose(1, 0, 2, 3).reshape(E_local, model_size * C, d))
+        with jax.named_scope("moe_exchange"):
+            buf = buf.reshape(model_size, E_local * C, d)
+            buf = alltoall.all_to_all(buf, model_axis, mode=cfg.a2a,
+                                      inner=cfg.a2a_inner)
+            # (M, E_local·C, d) source-major → (E_local, M·C, d)
+            buf = (buf.reshape(model_size, E_local, C, d)
+                   .transpose(1, 0, 2, 3).reshape(E_local, model_size * C, d))
     else:
         buf = buf.reshape(E_local, C, d)
 
@@ -339,35 +374,43 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
         # a reduce-scatter returns each rank's own tokens.  Replaces the
         # per-layer multi-GB ZeRO-3 weight gather with MB-scale token
         # collectives.
-        buf = lax.all_gather(buf, expert_tp_axis, axis=1, tiled=True)
-        h = expert_ffn(params, buf.astype(params["w_up"].dtype), act)
-        h = lax.psum_scatter(h, expert_tp_axis, scatter_dimension=1,
-                             tiled=True)
+        with jax.named_scope("moe_exchange"):
+            buf = lax.all_gather(buf, expert_tp_axis, axis=1, tiled=True)
+        with jax.named_scope("moe_experts"):
+            h = expert_ffn(params, buf.astype(params["w_up"].dtype), act)
+        with jax.named_scope("moe_exchange"):
+            h = lax.psum_scatter(h, expert_tp_axis, scatter_dimension=1,
+                                 tiled=True)
     else:
-        h = expert_ffn(params, buf.astype(params["w_up"].dtype), act)
+        with jax.named_scope("moe_experts"):
+            h = expert_ffn(params, buf.astype(params["w_up"].dtype), act)
 
     # -- 5. AllToAll (return) -------------------------------------------------
     if model_size > 1:
-        h = (h.reshape(E_local, model_size, C, d)
-             .transpose(1, 0, 2, 3).reshape(model_size, E_local * C, d))
-        h = alltoall.all_to_all(h, model_axis, mode=cfg.a2a, inner=cfg.a2a_inner)
-        h = h.reshape(E * C, d)
+        with jax.named_scope("moe_exchange"):
+            h = (h.reshape(E_local, model_size, C, d)
+                 .transpose(1, 0, 2, 3).reshape(model_size, E_local * C, d))
+            h = alltoall.all_to_all(h, model_axis, mode=cfg.a2a,
+                                    inner=cfg.a2a_inner)
+            h = h.reshape(E * C, d)
     else:
         h = h.reshape(E * C, d)
 
     # -- 6. reverse layout transform + combine --------------------------------
-    if dispatch == "sort":
-        if cfg.use_pallas_gate:
-            from repro.kernels import ops as kops
-            y = kops.layout_combine(h, plan.slot, plan.weight)
+    with jax.named_scope("moe_layout"):
+        if dispatch == "sort":
+            if cfg.use_pallas_gate:
+                from repro.kernels import ops as kops
+                y = kops.layout_combine(h, plan.slot, plan.weight)
+            else:
+                y = layout.combine_gather(h, plan)
         else:
-            y = layout.combine_gather(h, plan)
-    else:
-        y = layout.combine_dense(h, plan, E, C)
+            y = layout.combine_dense(h, plan, E, C)
 
     if pmean_axes:
-        aux = lax.pmean(aux, pmean_axes)
-        metrics = {k: lax.pmean(v, pmean_axes) for k, v in metrics.items()}
+        with jax.named_scope("moe_gate"):
+            aux = lax.pmean(aux, pmean_axes)
+            metrics = {k: lax.pmean(v, pmean_axes) for k, v in metrics.items()}
     return y.astype(x.dtype), aux, metrics
 
 
@@ -543,8 +586,9 @@ def sharded_moe_apply(mesh: jax.sharding.Mesh, cfg: MoEConfig,
     # COMPUTE dtype.  The cast is outside shard_map, so the ZeRO-3
     # all-gather XLA inserts at the shard_map boundary moves bf16, not
     # f32 — halving the largest FSDP collective and its HBM transient.
-    params = {k: (v.astype(x.dtype) if k != "gate_w" else v)
-              for k, v in params.items()}
+    with jax.named_scope("moe_experts"):
+        params = {k: (v.astype(x.dtype) if k != "gate_w" else v)
+                  for k, v in params.items()}
 
     # trace-time "auto" resolution (core/tuning.py): the per-shard token
     # count, width and dtype are all static here, so the resolved cfg is

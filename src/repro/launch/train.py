@@ -19,10 +19,22 @@ once ``TrainConfig.max_skipped_steps`` CONSECUTIVE steps were skipped.
 tests and bench tooling diff trajectories without parsing stdout, and
 ``--inject site:mode@steps`` arms the deterministic fault harness
 (``core/faults.py``) from the CLI.
+
+Tracing: each step of the loop runs under
+``jax.profiler.StepTraceAnnotation("train", step_num=s)``, its parts
+under ``TraceAnnotation`` spans ``train.batch`` (host batch and
+``device_put``), ``train.step`` (the jitted call), ``train.fetch`` (the
+metrics to the host, which waits for the device) and
+``train.checkpoint``.  ``--profile DIR`` writes a profiler trace of
+``PROFILE_STEPS`` steps after the first ``PROFILE_SKIP``, where those
+spans sit on one clock with the device's ops.  An MoE model's log line
+and history carry the routing counters ``expert_load_ratio`` (busiest
+expert over the mean) and ``dropped_share`` (assignments dropped).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 from typing import Any, NamedTuple
@@ -43,6 +55,9 @@ from repro.training import make_train_step
 from repro.training.train_step import TrainState, init_train_state
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 
+PROFILE_SKIP = 3      # steps before --profile's trace starts (compiles)
+PROFILE_STEPS = 10    # steps --profile traces
+
 
 class Trainer(NamedTuple):
     """The pieces :func:`run` drives, placed on one mesh."""
@@ -52,10 +67,35 @@ class Trainer(NamedTuple):
     data: SyntheticLM
     batch_sharding: Any
     rng: jax.Array
+    max_skipped_steps: int
 
     def batch(self, step: int):
         """The global batch of ``step``, placed by the mesh's batch rules."""
         return jax.device_put(self.data.next_batch(step), self.batch_sharding)
+
+    def dispatch(self, state: TrainState, s: int):
+        """Send global step ``s``: its batch and the key ``fold_in(rng,
+        s)``.  Returns ``(state, metrics)``, both still on the device."""
+        with jax.profiler.TraceAnnotation("train.batch"):
+            batch = self.batch(s)
+        with jax.profiler.TraceAnnotation("train.step"):
+            return self.step(state, batch, jax.random.fold_in(self.rng, s))
+
+    def fetch(self, m) -> dict:
+        """A step's metrics on the host; this waits for the step."""
+        with jax.profiler.TraceAnnotation("train.fetch"):
+            return {k: float(v) for k, v in m.items()}
+
+    def check(self, s: int, m: dict) -> None:
+        """Fail fast once ``max_skipped_steps`` consecutive steps were
+        skipped as non-finite."""
+        if m["nonfinite_streak"] >= self.max_skipped_steps:
+            raise RuntimeError(
+                f"aborting at step {s}: {int(m['nonfinite_streak'])} "
+                f"consecutive non-finite steps were skipped (>= "
+                f"max_skipped_steps={self.max_skipped_steps}) — the run "
+                f"is diverging; restore an earlier checkpoint, lower the "
+                f"lr, or enable loss_scale='dynamic'")
 
 
 def build(cfg: ModelConfig, tcfg: TrainConfig, mesh, *, batch: int, seq: int,
@@ -74,7 +114,7 @@ def build(cfg: ModelConfig, tcfg: TrainConfig, mesh, *, batch: int, seq: int,
                    donate_argnums=(0,))
     return Trainer(jax.jit(init, out_shardings=state_sh)(rng), state_sh, step,
                    data, mesh_lib.batch_shardings(mesh, data.next_batch(0)),
-                   rng)
+                   rng, tcfg.max_skipped_steps)
 
 
 def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
@@ -83,7 +123,7 @@ def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
         ckpt_every: int = None, ckpt_keep: int = 3, resume: bool = False,
         seed: int = 0, loss_scale="none", history_out: str = None,
         faults: faults_mod.FaultPlan = None, tune: str = "auto",
-        fabric=None):
+        fabric=None, profile_dir: str = None):
     if (ckpt_every or resume) and not ckpt_dir:
         raise ValueError("--ckpt-every/--resume require --ckpt-dir")
     cfg = configs.smoke_config(arch) if smoke else configs.get_config(arch)
@@ -98,7 +138,7 @@ def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
         print(f"tune={tmode} fabric={tfab}")
     tr = build(cfg, tcfg, mesh, batch=batch, seq=seq, seed=seed,
                faults=faults)
-    state, rng = tr.state, tr.rng
+    state = tr.state
     start = 0
     if resume:
         if latest_step(ckpt_dir) is not None:
@@ -111,31 +151,32 @@ def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M mesh={dict(mesh.shape)}")
     history = []
     t0 = time.time()
-    with faults_mod.active(faults):
+    trace_from = start + PROFILE_SKIP
+    with faults_mod.active(faults), contextlib.ExitStack() as profiler:
         for s in range(start, steps):
             faults_mod.crash_point("train.loop", index=s)
-            t_step = time.perf_counter()
-            state, m = tr.step(state, tr.batch(s), jax.random.fold_in(rng, s))
-            m = {k: float(v) for k, v in m.items()}
-            # host seconds to the step's metrics (float() waits for them)
-            history.append({"step": s, **m,
-                            "step_s": time.perf_counter() - t_step})
-            if s % log_every == 0 or s == steps - 1:
-                dt = time.time() - t0
-                tput = batch * seq * (s + 1 - start) / max(dt, 1e-9)
-                print(f"step {s:5d} loss {m['loss']:.4f} ce {m['ce']:.4f} "
-                      f"aux {m['aux']:.4f} gnorm {m['grad_norm']:.2f} "
-                      f"skip {m['skipped']:.0f} streak "
-                      f"{m['nonfinite_streak']:.0f} tok/s {tput:,.0f}")
-            if m["nonfinite_streak"] >= tcfg.max_skipped_steps:
-                raise RuntimeError(
-                    f"aborting at step {s}: {int(m['nonfinite_streak'])} "
-                    f"consecutive non-finite steps were skipped (>= "
-                    f"max_skipped_steps={tcfg.max_skipped_steps}) — the run "
-                    f"is diverging; restore an earlier checkpoint, lower the "
-                    f"lr, or enable loss_scale='dynamic'")
-            if ckpt_every and (s + 1) % ckpt_every == 0 and s + 1 < steps:
-                save_checkpoint(ckpt_dir, state, s + 1, keep=ckpt_keep)
+            if profile_dir and s == trace_from:
+                profiler.enter_context(jax.profiler.trace(profile_dir))
+            with jax.profiler.StepTraceAnnotation("train", step_num=s):
+                t_step = time.perf_counter()
+                state, m = tr.dispatch(state, s)
+                m = tr.fetch(m)
+                # host seconds to the step's metrics (fetch waits for them)
+                history.append({"step": s, **m,
+                                "step_s": time.perf_counter() - t_step})
+                if s % log_every == 0 or s == steps - 1:
+                    dt = time.time() - t0
+                    tput = batch * seq * (s + 1 - start) / max(dt, 1e-9)
+                    print(_log_line(s, m, tput))
+                tr.check(s, m)
+                if ckpt_every and (s + 1) % ckpt_every == 0 and s + 1 < steps:
+                    with jax.profiler.TraceAnnotation("train.checkpoint"):
+                        save_checkpoint(ckpt_dir, state, s + 1,
+                                        keep=ckpt_keep)
+            if s + 1 == trace_from + PROFILE_STEPS:
+                profiler.close()
+    if profile_dir:
+        print("profile written to", profile_dir)
     if ckpt_dir:
         save_checkpoint(ckpt_dir, state, steps, keep=ckpt_keep)
         print("checkpoint saved to", ckpt_dir)
@@ -146,6 +187,17 @@ def run(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
                        "history": history}, f, indent=1)
         print("history written to", history_out)
     return state, history
+
+
+def _log_line(s: int, m: dict, tput: float) -> str:
+    line = (f"step {s:5d} loss {m['loss']:.4f} ce {m['ce']:.4f} "
+            f"aux {m['aux']:.4f} gnorm {m['grad_norm']:.2f} "
+            f"skip {m['skipped']:.0f} streak "
+            f"{m['nonfinite_streak']:.0f}")
+    if "expert_load_ratio" in m:
+        line += (f" load {m['expert_load_ratio']:.3f} "
+                 f"drop {m['dropped_share']:.4f}")
+    return line + f" tok/s {tput:,.0f}"
 
 
 def main():
@@ -174,6 +226,9 @@ def main():
                     help="'none', 'dynamic', or a static float (bf16 stability)")
     ap.add_argument("--history-out", default=None,
                     help="dump the per-step metric history as JSON")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help=f"write a profiler trace of {PROFILE_STEPS} steps "
+                         f"after the first {PROFILE_SKIP} to DIR")
     ap.add_argument("--inject", action="append", default=[],
                     help="fault spec 'site:mode@steps' (repeatable), e.g. "
                          "'train.grads:nan@3' or 'ckpt.data_tmp_written:kill@20'")
@@ -197,7 +252,8 @@ def main():
         ckpt_every=args.ckpt_every, ckpt_keep=args.ckpt_keep,
         resume=args.resume, log_every=args.log_every, seed=args.seed,
         loss_scale=args.loss_scale, history_out=args.history_out,
-        faults=faults, tune=args.tune, fabric=args.fabric)
+        faults=faults, tune=args.tune, fabric=args.fabric,
+        profile_dir=args.profile)
 
 
 if __name__ == "__main__":

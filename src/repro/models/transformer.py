@@ -179,39 +179,50 @@ def _block_window(kind: str, cfg: ModelConfig, long_context: bool) -> Optional[i
 
 def _apply_attn_mlp(bp, shared, x, kind, cfg: ModelConfig, mesh, mode, cache,
                     positions, long_context, rng):
+    """Attention + MLP (or MoE) block.  Returns ``(x, cache, aux,
+    router)``: ``router`` is the MoE layer's metrics dict
+    (``balance.METRIC_KEYS``), None for a dense block."""
     win = _block_window(kind, cfg, long_context)
     causal = not cfg.encoder_only
-    h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
-    if mode == "decode":
-        ring = win is not None and cache["k"].shape[1] == win
-        a, cache = attn_lib.decode_attention(bp["attn"], h, cache, cfg.attention,
-                                             ring=ring, window=win)
-    else:
-        a, kv = attn_lib.full_attention(bp["attn"], h, cfg.attention,
-                                        positions=positions, causal=causal,
-                                        window=win, mesh=mesh)
-        if cache is not None:
+    with jax.named_scope("attention"):
+        h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
+        if mode == "decode":
             ring = win is not None and cache["k"].shape[1] == win
-            cache = attn_lib.fill_cache(cache, kv, ring=ring)
+            a, cache = attn_lib.decode_attention(bp["attn"], h, cache,
+                                                 cfg.attention, ring=ring,
+                                                 window=win)
+        else:
+            a, kv = attn_lib.full_attention(bp["attn"], h, cfg.attention,
+                                            positions=positions,
+                                            causal=causal, window=win,
+                                            mesh=mesh)
+            if cache is not None:
+                ring = win is not None and cache["k"].shape[1] == win
+                cache = attn_lib.fill_cache(cache, kv, ring=ring)
     x = x + a
     aux = jnp.zeros((), jnp.float32)
-    h = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
+    router = None
     if kind == "moe":
+        with jax.named_scope("moe_gate"):
+            h = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
         # expert TP needs a data axis to shard f over; sharded_moe_apply
         # rejects axes missing from the mesh rather than silently no-op'ing
         tp = decode_expert_tp_axis(mesh) if mode == "decode" else None
-        y, aux, _ = moe_lib.sharded_moe_apply(
+        y, aux, router = moe_lib.sharded_moe_apply(
             mesh, cfg.moe, bp["moe"], h, num_experts=cfg.moe.num_experts,
             act=cfg.act, rng=rng, expert_tp_axis=tp)
         if "shared_mlp" in bp:
             y = y + layers.apply_mlp(bp["shared_mlp"], h, cfg.act)
     else:
+        h = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
         y = layers.apply_mlp(bp["mlp"], h, cfg.act)
-    return x + y, cache, aux
+    return x + y, cache, aux, router
 
 
 def _apply_block(j, kind, bp, shared, x, cfg, mesh, mode, cache, positions,
                  long_context, rng):
+    """One block: ``(x, cache, aux, router)``, as :func:`_apply_attn_mlp`
+    (``router`` is None but for a MoE block)."""
     zero = jnp.zeros((), jnp.float32)
     if kind in ("attn", "local", "global", "dense", "moe"):
         return _apply_attn_mlp(bp, shared, x, kind, cfg, mesh, mode, cache,
@@ -249,7 +260,7 @@ def _apply_block(j, kind, bp, shared, x, cfg, mesh, mode, cache, positions,
                 if (cache is not None or mode == "decode") else None
         else:
             new_cache = {"mamba": mstate} if (cache is not None or mode == "decode") else None
-        return x, new_cache, zero
+        return x, new_cache, zero, None
     if kind == "rwkv":
         h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
         if mode == "decode":
@@ -263,7 +274,7 @@ def _apply_block(j, kind, bp, shared, x, cfg, mesh, mode, cache, positions,
         h = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
         x = x + layers.apply_mlp(bp["mlp"], h, cfg.act)   # channel mix
         new_cache = {"rwkv": rstate} if (cache is not None or mode == "decode") else None
-        return x, new_cache, zero
+        return x, new_cache, zero, None
     raise ValueError(kind)
 
 
@@ -340,18 +351,34 @@ def _embed_inputs(params, cfg: ModelConfig, inputs: jax.Array, dtype, mesh=None)
     return layers.embed(table, inputs, dtype, cfg.scale_embeddings)
 
 
+def _router_stats(routers, num_experts: int):
+    """One block stack's MoE metrics → ``(expert_load_ratio, dropped
+    share summed over the layers)``: the busiest expert's assignments
+    over the mean, largest over the layers."""
+    ratio = jnp.max(jnp.stack([r["expert_load_max"] for r in routers]))
+    dropped = sum(r["dropped_share"] for r in routers)
+    return ratio * num_experts, dropped
+
+
 def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
             mesh=None, rng: Optional[jax.Array] = None,
             caches=None, collect_caches: bool = False,
             long_context: bool = False, remat: str = "none",
-            positions: Optional[jax.Array] = None):
+            positions: Optional[jax.Array] = None,
+            router_metrics: bool = False):
     """Full-sequence pass (train / prefill).
 
     inputs: (B, S) int tokens, or (B, S, d) embeddings for frontend archs.
-    Returns (hidden (B,S,d), aux_loss, caches|None).
+    Returns (hidden (B,S,d), aux_loss, caches|None); with
+    ``router_metrics`` (a model with MoE blocks) a fourth item, the
+    routing counters: ``expert_load_ratio`` (the busiest expert's
+    assignments over the mean, largest over the MoE layers: 1 balanced,
+    E collapsed) and ``dropped_share`` (assignments dropped by capacity
+    or the grouped-EP bound over all routed ones, over the MoE layers).
     """
     dtype = jnp.dtype(cfg.dtype)
-    x = _embed_inputs(params, cfg, inputs, dtype, mesh)
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, cfg, inputs, dtype, mesh)
     B, S = x.shape[:2]
     if positions is None:
         positions = jnp.arange(S, dtype=jnp.int32)
@@ -364,17 +391,21 @@ def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
         x, aux, rng = carry
         bparams, cache_in = xs
         rng, *rks = jax.random.split(rng, len(cfg.block_pattern) + 1)
-        new_caches = []
+        new_caches, routers = [], []
         for j, kind in enumerate(cfg.block_pattern):
             c_in = cache_in[j] if cache_in is not None else None
-            x, c_out, a = _apply_block(j, kind, bparams[j], shared, x, cfg,
-                                       mesh, "full", c_in, positions,
-                                       long_context, rks[j])
+            x, c_out, a, router = _apply_block(
+                j, kind, bparams[j], shared, x, cfg, mesh, "full", c_in,
+                positions, long_context, rks[j])
             x = shard_act(x, mesh)
             aux = aux + a
             new_caches.append(c_out)
+            if router is not None:
+                routers.append(router)
         out_caches = tuple(new_caches) if cache_in is not None else None
-        return (x, aux, rng), out_caches
+        stats = (_router_stats(routers, cfg.moe.num_experts)
+                 if router_metrics else None)
+        return (x, aux, rng), (out_caches, stats)
 
     body = super_body
     if remat == "block":
@@ -386,9 +417,16 @@ def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
     if collect_caches and caches is None:
         caches = init_caches(cfg, B, S, long_context=long_context, dtype=dtype)
     xs = (params["blocks"], caches)
-    (x, aux, _), out_caches = lax.scan(body, (x, jnp.zeros((), jnp.float32), rng), xs)
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux, out_caches
+    (x, aux, _), (out_caches, stats) = lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), rng), xs)
+    with jax.named_scope("head_loss"):
+        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if not router_metrics:
+        return x, aux, out_caches
+    ratio, dropped = stats
+    n_moe = cfg.num_super_blocks * cfg.block_pattern.count("moe")
+    return x, aux, out_caches, {"expert_load_ratio": jnp.max(ratio),
+                                "dropped_share": jnp.sum(dropped) / n_moe}
 
 
 def logits_from_hidden(params, cfg: ModelConfig, h: jax.Array, mesh=None):
@@ -417,9 +455,9 @@ def decode_step(params: Dict[str, Any], token: jax.Array, caches, cfg: ModelConf
         rng, *rks = jax.random.split(rng, len(cfg.block_pattern) + 1)
         new_caches = []
         for j, kind in enumerate(cfg.block_pattern):
-            x, c_out, a = _apply_block(j, kind, bparams[j], shared, x, cfg,
-                                       mesh, "decode", cache_in[j], None,
-                                       long_context, rks[j])
+            x, c_out, a, _ = _apply_block(j, kind, bparams[j], shared, x,
+                                          cfg, mesh, "decode", cache_in[j],
+                                          None, long_context, rks[j])
             aux = aux + a
             new_caches.append(c_out)
         return (x, aux, rng), tuple(new_caches)

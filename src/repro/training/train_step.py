@@ -154,12 +154,20 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
     ``batch`` holds the GLOBAL batch; with ``tcfg.microbatches > 1`` it is
     split on the batch axis and accumulated via scan.
 
+    A model with MoE blocks adds the routing counters to the metrics:
+    ``expert_load_ratio`` (largest over the microbatches) and
+    ``dropped_share`` (their mean), see ``transformer.forward``.  The
+    cross-entropy runs under the ``head_loss`` named scope and the
+    guard, clipping and AdamW under ``optimizer``, so their device ops
+    say so in the compiled step's op metadata.
+
     ``faults`` (a ``core.faults.FaultPlan``) arms the traced injection
     seams at trace time; None (production) inserts no extra ops.
     """
     sched = make_schedule(tcfg)
     dynamic = tcfg.loss_scale == "dynamic"
     static_scale = not dynamic and float(tcfg.loss_scale) == 1.0
+    routed = "moe" in cfg.block_pattern
 
     def train_step(state: TrainState, batch, rng) -> Tuple[TrainState, Dict]:
         mbs = tcfg.microbatches
@@ -167,22 +175,26 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
                  else state.loss_scale.astype(jnp.float32))
 
         def loss_fn(params, mb, r):
-            h, aux, _ = T.forward(params, mb["inputs"], cfg, mesh=mesh, rng=r,
-                                  remat=tcfg.remat)
+            out = T.forward(params, mb["inputs"], cfg, mesh=mesh, rng=r,
+                            remat=tcfg.remat, router_metrics=routed)
+            h, aux = out[0], out[1]
+            router = out[3] if routed else {}
             h = faults_mod.apply_traced(faults, "train.activations",
                                         state.step, h)
-            ce = chunked_ce_loss(params, cfg, h, mb["targets"],
-                                 mb["loss_mask"], mesh)
+            with jax.named_scope("head_loss"):
+                ce = chunked_ce_loss(params, cfg, h, mb["targets"],
+                                     mb["loss_mask"], mesh)
             loss = ce + aux
             loss = faults_mod.apply_traced(faults, "train.loss",
                                            state.step, loss)
             scaled = loss if static_scale else loss * scale
-            return scaled, (loss, ce, aux)
+            return scaled, (loss, ce, aux, router)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
         if mbs == 1:
-            (_, (loss, ce, aux)), grads = grad_fn(state.params, batch, rng)
+            (_, (loss, ce, aux, router)), grads = grad_fn(state.params,
+                                                          batch, rng)
         else:
             def split(x):
                 return x.reshape(mbs, x.shape[0] // mbs, *x.shape[1:])
@@ -191,63 +203,70 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
 
             def body(acc, xs):
                 mb, r = xs
-                (_, (l, c, a)), g = grad_fn(state.params, mb, r)
+                (_, (l, c, a, router)), g = grad_fn(state.params, mb, r)
                 gacc, lacc, cacc, aacc = acc
                 gacc = jax.tree.map(jnp.add, gacc, g)
-                return (gacc, lacc + l, cacc + c, aacc + a), None
+                return (gacc, lacc + l, cacc + c, aacc + a), router
 
             zeros = jax.tree.map(jnp.zeros_like, state.params)
-            (grads, loss, ce, aux), _ = lax.scan(
+            (grads, loss, ce, aux), router = lax.scan(
                 body, (zeros, jnp.zeros(()), jnp.zeros(()), jnp.zeros(())),
                 (mb_batch, rngs))
             grads = jax.tree.map(lambda g: g / mbs, grads)
             loss, ce, aux = loss / mbs, ce / mbs, aux / mbs
+            if routed:
+                # over the microbatches: the busiest load, the mean drops
+                router = {
+                    "expert_load_ratio": jnp.max(router["expert_load_ratio"]),
+                    "dropped_share": jnp.mean(router["dropped_share"])}
 
         grads = faults_mod.apply_traced(faults, "train.grads", state.step,
                                         grads)
+        with jax.named_scope("optimizer"):
+            # -- non-finite guard -----------------------------------------
+            # Under single-controller jit these arrays are global, so
+            # reducing them IS the cross-device all-reduce of the isfinite
+            # check (XLA inserts the collective for sharded leaves).
+            ok = jnp.isfinite(loss)
+            for g in jax.tree.leaves(grads):
+                ok = ok & jnp.all(jnp.isfinite(g))
 
-        # -- non-finite guard ---------------------------------------------
-        # Under single-controller jit these arrays are global, so reducing
-        # them IS the cross-device all-reduce of the isfinite check (XLA
-        # inserts the collective for sharded leaves).
-        ok = jnp.isfinite(loss)
-        for g in jax.tree.leaves(grads):
-            ok = ok & jnp.all(jnp.isfinite(g))
+            if not static_scale:
+                # unscale AFTER the finite check (an overflowed Inf grad
+                # must be seen as non-finite, not Inf/scale); skipped steps
+                # never consume the unscaled values.
+                inv = (jnp.float32(1.0) / scale)
+                grads = jax.tree.map(lambda g: (g * inv.astype(g.dtype)),
+                                     grads)
 
-        if not static_scale:
-            # unscale AFTER the finite check (an overflowed Inf grad must
-            # be seen as non-finite, not Inf/scale); skipped steps never
-            # consume the unscaled values.
-            inv = (jnp.float32(1.0) / scale)
-            grads = jax.tree.map(lambda g: (g * inv.astype(g.dtype)), grads)
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = sched(state.step)
+            new_params, new_opt = adamw_update(grads, state.opt,
+                                               state.params, tcfg, lr)
+            # bad step: params, moments AND the bias-correction count keep
+            # their old bits — the update never happened.
+            new_params = _tree_where(ok, new_params, state.params)
+            new_opt = _tree_where(ok, new_opt, state.opt)
 
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = sched(state.step)
-        new_params, new_opt = adamw_update(grads, state.opt, state.params,
-                                           tcfg, lr)
-        # bad step: params, moments AND the bias-correction count keep
-        # their old bits — the update never happened.
-        new_params = _tree_where(ok, new_params, state.params)
-        new_opt = _tree_where(ok, new_opt, state.opt)
-
-        oki = ok.astype(jnp.int32)
-        skipped = state.skipped + (1 - oki)
-        streak = jnp.where(ok, 0, state.nonfinite_streak + 1)
-        good = jnp.where(ok, state.good_streak + 1, 0)
-        if dynamic:
-            grow = ok & (good >= tcfg.loss_scale_growth_interval)
-            new_scale = jnp.where(
-                ok,
-                jnp.where(grow, jnp.minimum(scale * 2.0, _SCALE_MAX), scale),
-                jnp.maximum(scale * 0.5, _SCALE_MIN))
-            good = jnp.where(grow, 0, good)
-        else:
-            new_scale = state.loss_scale
+            oki = ok.astype(jnp.int32)
+            skipped = state.skipped + (1 - oki)
+            streak = jnp.where(ok, 0, state.nonfinite_streak + 1)
+            good = jnp.where(ok, state.good_streak + 1, 0)
+            if dynamic:
+                grow = ok & (good >= tcfg.loss_scale_growth_interval)
+                new_scale = jnp.where(
+                    ok,
+                    jnp.where(grow, jnp.minimum(scale * 2.0, _SCALE_MAX),
+                              scale),
+                    jnp.maximum(scale * 0.5, _SCALE_MIN))
+                good = jnp.where(grow, 0, good)
+            else:
+                new_scale = state.loss_scale
 
         metrics = {"loss": loss, "ce": ce, "aux": aux,
                    "grad_norm": gnorm, "lr": lr,
                    "skipped": skipped, "nonfinite_streak": streak,
-                   "loss_scale": new_scale}
+                   "loss_scale": new_scale, **router}
         return TrainState(new_params, new_opt, state.step + 1,
                           skipped=skipped, nonfinite_streak=streak,
                           good_streak=good, loss_scale=new_scale), metrics

@@ -6,9 +6,12 @@ The TPU compiler refuses what the interpreter accepts: block shapes that
 are not (8, 128)-aligned, more scoped VMEM than a kernel may use, vector
 loads from SMEM, unaligned HBM slices.  Each case is a kernel-level
 compile of a second or two.  The topology is described inside a fixture
-(only the worker that runs this file loads the TPU compiler).
+(only the worker that runs this file loads the TPU compiler).  One
+smoke-width train step compiles whole, to check where its flash kernels
+sit among the step's named scopes.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,3 +96,42 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text(), name
     # an XLA-side temporary larger than the chip would not run either
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_train_step_keeps_the_flash_kernel_under_attention(topo, monkeypatch):
+    """The smoke-width train step at seq 1024 (the flash path), compiled
+    for a v5e: the kernels' ``pallas_vmem/pallas_call``, forward and
+    backward, sit directly under the step's ``attention`` scope, where
+    the benchmark's ``flash_attn_ms`` and ``attn_block_ms`` find them."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import configs
+    from repro.core.config import TrainConfig
+    from repro.kernels import ops as kops
+    from repro.launch import mesh as mesh_lib
+    from repro.training import make_train_step
+    from repro.training.train_step import init_train_state
+
+    monkeypatch.setattr(kops, "INTERPRET", False)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
+                             ("data", "model"))
+    cfg, tcfg = configs.smoke_config("hetumoe-paper-16e"), TrainConfig()
+    shapes = jax.eval_shape(lambda r: init_train_state(r, cfg, tcfg),
+                            jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, mesh_lib.state_shardings(mesh, shapes))
+    rep = NamedSharding(mesh, P())
+    tok = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=rep)
+    batch = {"inputs": tok, "targets": tok,
+             "loss_mask": jax.ShapeDtypeStruct((1, S), jnp.float32,
+                                               sharding=rep)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    text = jax.jit(make_train_step(cfg, tcfg, mesh)).lower(
+        state, batch, key).compile().as_text()
+    kernels = set(re.findall(r'op_name="([^"]*pallas_vmem/pallas_call)"',
+                             text))
+    assert kernels and all("/attention/pallas_vmem/pallas_call" in k
+                           for k in kernels), kernels
+    assert any(k.startswith("jit(train_step)/transpose(") for k in kernels)
