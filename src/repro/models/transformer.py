@@ -429,9 +429,18 @@ def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
                                 "dropped_share": jnp.sum(dropped) / n_moe}
 
 
+def head_weight(params, cfg: ModelConfig) -> jax.Array:
+    """The unembedding (d, V): the embedding's transpose when tied."""
+    return (params["embed"].T if cfg.tie_embeddings and cfg.frontend is None
+            else params["lm_head"])
+
+
 def logits_from_hidden(params, cfg: ModelConfig, h: jax.Array, mesh=None):
-    w = params["embed"].T if cfg.tie_embeddings and cfg.frontend is None \
-        else params["lm_head"]
+    return unembed(head_weight(params, cfg), cfg, h, mesh)
+
+
+def unembed(w: jax.Array, cfg: ModelConfig, h: jax.Array, mesh=None):
+    """Logits (..., V) of hidden states h (..., d) under the head w (d, V)."""
     logits = h @ w.astype(h.dtype)
     if cfg.final_softcap:
         logits = layers.softcap(logits.astype(jnp.float32), cfg.final_softcap)

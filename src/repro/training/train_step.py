@@ -4,8 +4,11 @@ and the non-finite skip-step guard.
 Chunked cross-entropy: the unembed + softmax-CE is scanned over sequence
 chunks so the full (B, S, V) logits tensor is NEVER materialized — at
 gemma2's V=256k that tensor is ~2 GB/device f32 on train_4k; chunking
-caps it at (B, S/nc, V).  This is a beyond-paper memory optimization
-recorded in EXPERIMENTS.md §Perf.
+caps it at (B, S/nc, V).  Under differentiation it is a fused linear
+cross-entropy: each chunk takes its VJP inside the forward scan, which
+carries the head weight's f32 gradient and stacks dh, so the backward
+neither keeps nor recomputes any chunk's logits (three logits-sized
+matmuls a step, not four).  Both are beyond-paper optimizations.
 
 Gradient accumulation: ``lax.scan`` over microbatches (the standard
 jax idiom — one compiled step regardless of accumulation factor).
@@ -83,30 +86,78 @@ def _auto_chunks(S: int, V: int) -> int:
 
 def chunked_ce_loss(params, cfg: ModelConfig, h: jax.Array, targets: jax.Array,
                     mask: jax.Array, mesh=None, num_chunks: Optional[int] = None):
-    """Scan the unembed+CE over sequence chunks.  h (B,S,d) → scalar."""
-    B, S, d = h.shape
+    """Scan the unembed+CE over sequence chunks.  h (B,S,d) → scalar.
+
+    Differentiable in ``params`` (through the head weight) and ``h``;
+    the gradient is taken in the forward scan (``_fused_ce``)."""
+    S = h.shape[1]
     nc = num_chunks or _auto_chunks(S, cfg.vocab_size)
     while S % nc:
         nc -= 1
-    hc = h.reshape(B, nc, S // nc, d).transpose(1, 0, 2, 3)
-    tc = targets.reshape(B, nc, S // nc).transpose(1, 0, 2)
-    mc = mask.reshape(B, nc, S // nc).transpose(1, 0, 2)
+    return _fused_ce(cfg, mesh, nc, h, T.head_weight(params, cfg),
+                     targets, mask)
 
-    # remat the chunk body: without it, scan's VJP stacks every chunk's
-    # exp(logits) residual — i.e. the full (S, V) f32 tensor the chunking
-    # was supposed to avoid (22.6 GiB/dev for internvl2 train_4k).
-    @jax.checkpoint
-    def body(acc, xs):
-        hi, ti, mi = xs
-        logits = T.logits_from_hidden(params, cfg, hi, mesh).astype(jnp.float32)
+
+def _ce_scan(cfg: ModelConfig, mesh, nc: int, h, w, targets, mask,
+             inv=None):
+    """Masked NLL summed over ``nc`` sequence chunks.  Given ``inv``, the
+    cotangent of that sum, each chunk also takes its VJP while its logits
+    are live, and this returns (sum, (dh (B,S,d), dW (d,V) in f32))."""
+    B, S, d = h.shape
+    chunk = lambda x: x.reshape(B, nc, S // nc, *x.shape[2:]).swapaxes(0, 1)
+
+    def nll(hi, w, ti, mi):
+        logits = T.unembed(w, cfg, hi, mesh).astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, ti[..., None], axis=-1)[..., 0]
-        nll = (lse - gold) * mi
-        return (acc[0] + jnp.sum(nll), acc[1] + jnp.sum(mi)), None
+        return jnp.sum((lse - gold) * mi)
 
-    (tot, cnt), _ = lax.scan(body, (jnp.zeros((), jnp.float32),) * 2,
-                             (hc, tc, mc))
-    return tot / jnp.maximum(cnt, 1.0)
+    def body(acc, xs):
+        hi, ti, mi = xs
+        if inv is None:
+            return acc + nll(hi, w, ti, mi), None
+        tot, dw = acc
+        s, pull = jax.vjp(lambda a, b: nll(a, b, ti, mi), hi, w)
+        dhi, dwi = pull(inv)
+        return (tot + s, dw + dwi), dhi
+
+    zero = jnp.zeros((), jnp.float32)
+    xs = (chunk(h), chunk(targets), chunk(mask))
+    if inv is None:
+        return lax.scan(body, zero, xs)[0]
+    (tot, dw), dh = lax.scan(body, (zero, jnp.zeros(w.shape, jnp.float32)),
+                             xs)
+    return tot, (dh.swapaxes(0, 1).reshape(B, S, d), dw)
+
+
+def _count(mask):
+    """The loss's token count (at least 1), in f32."""
+    return jnp.maximum(jnp.sum(mask, dtype=jnp.float32), 1.0)
+
+
+# Fused linear cross-entropy.  The loss is a scalar and its token count
+# is known before the scan, so each chunk's VJP can be taken in the
+# forward (cotangent 1/count); the backward only scales (dh, dW) by the
+# incoming cotangent.  No chunk's (S/nc, V) logits outlive its scan step,
+# so the (S, V) tensor the chunking avoids is never stacked as residuals.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _fused_ce(cfg, mesh, nc, h, w, targets, mask):
+    return _ce_scan(cfg, mesh, nc, h, w, targets, mask) / _count(mask)
+
+
+def _fused_ce_fwd(cfg, mesh, nc, h, w, targets, mask):
+    cnt = _count(mask)
+    tot, (dh, dw) = _ce_scan(cfg, mesh, nc, h, w, targets, mask,
+                             inv=1.0 / cnt)
+    return tot / cnt, (dh, dw.astype(w.dtype))
+
+
+def _fused_ce_bwd(cfg, mesh, nc, res, g):
+    dh, dw = res
+    return dh * g.astype(dh.dtype), dw * g.astype(dw.dtype), None, None
+
+
+_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 def donation_alias_pairs(tree) -> list:

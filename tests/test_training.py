@@ -1,5 +1,6 @@
 """Training substrate: loss falls, grad-accum equivalence, CE chunking,
 optimizer math, checkpoint roundtrip."""
+import re
 import tempfile
 
 import jax
@@ -54,19 +55,94 @@ def test_grad_accum_equivalence(mesh1):
                                    rtol=2e-3, atol=1e-5)
 
 
-def test_chunked_ce_equals_full(mesh1):
-    cfg = configs.smoke_config("yi-6b")
+def _ce_cfg(head):
+    """untied: yi's own lm_head; tied: gemma2's embedding transposed,
+    without its softcap; softcap: gemma2 as configured (tied, cap 30)."""
+    if head == "untied":
+        return configs.smoke_config("yi-6b")
+    cfg = configs.smoke_config("gemma2-9b")
+    return cfg if head == "softcap" else cfg.replace(final_softcap=None)
+
+
+def _full_ce(w, cfg, h, t, m):
+    """Plain cross-entropy over the full (B, S, V) logits, in f32."""
+    logits = h @ w
+    if cfg.final_softcap:
+        logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
+    nll = (jax.nn.logsumexp(logits, axis=-1)
+           - jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0])
+    return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "holes"])
+@pytest.mark.parametrize("nc", [1, 2, 8])
+@pytest.mark.parametrize("head", ["untied", "tied", "softcap"])
+def test_chunked_ce_equals_full(mesh1, head, nc, masked):
+    """The chunked, fused CE against the full-logits one: the loss, and
+    the gradients in h and in the head weight (for a tied head, the
+    embedding's, through the transpose), at the cotangent 1 and at the
+    dynamic loss scale's 2^15, which scales them exactly."""
+    cfg = _ce_cfg(head)
     p = T.init_model(RNG, cfg)
     B, S = 2, 32
-    h = jax.random.normal(RNG, (B, S, cfg.d_model), jnp.float32)
+    kh, kt, km = jax.random.split(jax.random.PRNGKey(1), 3)
+    h = jax.random.normal(kh, (B, S, cfg.d_model), jnp.float32)
+    t = jax.random.randint(kt, (B, S), 0, cfg.vocab_size)
+    m = (jax.random.uniform(km, (B, S)) > 0.3 if masked
+         else jnp.ones((B, S), bool)).astype(jnp.float32)
+    assert 0 < float(m.sum()) < B * S or not masked
+
+    loss, pull = jax.vjp(
+        lambda p, h: chunked_ce_loss(p, cfg, h, t, m, mesh1, num_chunks=nc),
+        p, h)
+    w = T.head_weight(p, cfg)
+    ref, (rw, rh) = jax.value_and_grad(_full_ce, (0, 2))(w, cfg, h, t, m)
+    np.testing.assert_allclose(float(loss), float(ref), rtol=1e-5)
+
+    gp, gh = pull(jnp.float32(1.0))
+    np.testing.assert_allclose(np.asarray(gh), np.asarray(rh),
+                               rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(T.head_weight(gp, cfg)),
+                               np.asarray(rw), rtol=1e-4, atol=1e-7)
+
+    scale = 2.0 ** 15
+    sp, sh = pull(jnp.float32(scale))
+    np.testing.assert_array_equal(np.asarray(sh), np.asarray(gh) * scale)
+    for a, b in zip(jax.tree.leaves(sp), jax.tree.leaves(gp)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b) * scale)
+
+
+def _vocab_dots(hlo_text: str, vocab: int) -> list:
+    """The dot instructions of a compiled module with an operand that has
+    a dimension of size ``vocab``."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo_text))
+    dots = re.findall(r"%([\w.\-]+) = \S+ dot\(%([\w.\-]+), %([\w.\-]+)\)",
+                      hlo_text)
+    return [d for d, *ops in dots
+            if any(str(vocab) in shapes.get(o, "").split(",") for o in ops)]
+
+
+@pytest.mark.parametrize("nc", [2, 8])
+def test_chunked_ce_grad_does_not_recompute_logits(mesh1, nc):
+    """Compiled value_and_grad of the CE holds three vocabulary-sized
+    matmuls: the logits, dh and dW, all in the forward scan.  A fourth
+    is the logits recomputed in the backward.  Without a gradient, the
+    jitted loss equals the fused forward's."""
+    cfg = configs.smoke_config("yi-6b")
+    assert cfg.vocab_size not in (cfg.d_model, 2, 32 // nc)
+    p = T.init_model(RNG, cfg)
+    B, S = 2, 32
+    h = jax.random.normal(RNG, (B, S, cfg.d_model), jnp.bfloat16)
     t = jax.random.randint(RNG, (B, S), 0, cfg.vocab_size)
-    m = jnp.ones((B, S))
-    for nc in (1, 2, 8):
-        li = float(chunked_ce_loss(p, cfg, h, t, m, mesh1, num_chunks=nc))
-        if nc == 1:
-            base = li
-        else:
-            np.testing.assert_allclose(li, base, rtol=1e-5)
+    m = jnp.ones((B, S)).at[:, :3].set(0.0)
+    loss = lambda p, h: chunked_ce_loss(p, cfg, h, t, m, mesh1, num_chunks=nc)
+    vg = jax.jit(jax.value_and_grad(loss, (0, 1)))
+    text = vg.lower(p, h).compile().as_text()
+    assert len(_vocab_dots(text, cfg.vocab_size)) == 3, \
+        _vocab_dots(text, cfg.vocab_size)
+    fused, _ = vg(p, h)
+    np.testing.assert_allclose(float(jax.jit(loss)(p, h)), float(fused),
+                               rtol=1e-6)
 
 
 def test_adamw_against_reference():
