@@ -1,6 +1,7 @@
 """Train step, head: device milliseconds per step, per chip, of the ops
 under the program's ``head_loss`` scope: the final norm, the output
-head, its recomputed logits and the cross-entropy."""
+head's logits, the cross-entropy and the head's gradients, which the
+fused cross-entropy takes in its forward pass."""
 from chipbench import scopes
 
 

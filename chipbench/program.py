@@ -26,27 +26,33 @@ import jax.numpy as jnp
 from chipbench import traffic as traffic_lib
 from chipbench.spec import Cell, SpecError
 
-# the config file's "model" numbers, and where the program keeps each
-_MODEL_KEYS = {
-    "num_layers": lambda c: c.num_layers,
-    "d_model": lambda c: c.d_model,
-    "vocab_size": lambda c: c.vocab_size,
-    "num_heads": lambda c: c.attention.num_heads,
-    "num_kv_heads": lambda c: c.attention.num_kv_heads,
+# the config file's "model" numbers that the program derives; every other
+# key is an attribute of the same name on the ModelConfig, its attention
+# or its MoE config
+_DERIVED = {
     "head_dim": lambda c: c.head_dim,
-    "rope_theta": lambda c: c.attention.rope_theta,
-    "norm_eps": lambda c: c.norm_eps,
-    "act": lambda c: c.act,
-    "num_experts": lambda c: c.moe.num_experts,
-    "gate": lambda c: c.moe.gate,
-    "capacity_factor": lambda c: c.moe.capacity_factor,
     "d_ff_expert": lambda c: c.moe.d_ff_expert or c.d_ff,
-    "aux_loss_weight": lambda c: c.moe.aux_loss_weight,
-    "router_z_loss_weight": lambda c: c.moe.router_z_loss_weight,
-    "dtype": lambda c: c.dtype,
-    "router_dtype": lambda c: c.moe.router_dtype,
-    "tie_embeddings": lambda c: c.tie_embeddings,
+    "experts_per_token": lambda c: _gate_k(c.moe),
 }
+# keys held to the paper model's value where a config file leaves them out
+_IMPLIED = {"block_pattern": ["moe"], "num_shared_experts": 0}
+
+
+def _gate_k(moe):
+    from repro.core import gating
+    return gating.gate_k(moe)
+
+
+def _lookup(cfg, key: str):
+    """The program's value of a config file's ``model`` key; ``KeyError``
+    where the program has no such number."""
+    if key in _DERIVED:
+        return _DERIVED[key](cfg)
+    for obj in (cfg, cfg.attention, cfg.moe):
+        if obj is not None and hasattr(obj, key):
+            value = getattr(obj, key)
+            return list(value) if isinstance(value, tuple) else value
+    raise KeyError(key)
 
 
 def _replace(obj, overrides: Dict[str, Any]):
@@ -59,19 +65,24 @@ def _replace(obj, overrides: Dict[str, Any]):
 
 def model_config(cell: Cell):
     """The program's ``ModelConfig`` for the cell: the registered arch with
-    the config's overrides and the cell's MoE path.  Raises where it
-    differs from the config file's ``model`` numbers."""
+    the config's overrides and the cell's MoE path.  Raises where a key of
+    the config file's ``model`` is missing from it or differs."""
     from repro import configs
-    from repro.core import gating
 
     cfg = configs.get_config(cell.config["arch"])
     cfg = _replace(cfg, cell.config.get("overrides", {}))
     cfg = _replace(cfg, {"moe": cell.workload.get("moe", {})})
-    want = cell.model
-    got = {k: f(cfg) for k, f in _MODEL_KEYS.items()}
-    got["experts_per_token"] = gating.gate_k(cfg.moe)
-    bad = {k: (got[k], want.get(k)) for k in got if got[k] != want.get(k)}
-    if bad or cfg.block_pattern != ("moe",) or cfg.moe.num_shared_experts:
+    want = {**_IMPLIED, **cell.model}
+    got = {}
+    for k in want:
+        try:
+            got[k] = _lookup(cfg, k)
+        except KeyError:
+            raise SpecError(f"{cell.name}: {cell.config['name']}.json states "
+                            f"{k!r}, which the program's config has not"
+                            ) from None
+    bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
+    if bad:
         raise SpecError(f"{cell.name}: the program's config differs from "
                         f"{cell.config['name']}.json (program, file): {bad}")
     return cfg
@@ -85,14 +96,18 @@ def train_config(cell: Cell):
 def canonical_leaves(params) -> Dict[str, Any]:
     """The program's parameter tree as ``{canonical name: array}``, one
     entry per layer of each stacked block leaf (``layers.<l>.attn.wq``),
-    the names the reference uses."""
+    the names the reference uses.  ``blocks`` holds one tree per position
+    of the block pattern, each leaf stacked over the super-blocks, so the
+    leaf of position ``j`` in super-block ``b`` is layer
+    ``b * period + j``."""
     out = {}
+    period = len(params.get("blocks", ()))
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
         keys = [getattr(p, "key", getattr(p, "idx", None)) for p in path]
         if keys[0] == "blocks":
             rest = ".".join(str(k) for k in keys[2:])
-            for layer in range(leaf.shape[0]):
-                out[f"layers.{layer}.{rest}"] = leaf[layer]
+            for b in range(leaf.shape[0]):
+                out[f"layers.{b * period + keys[1]}.{rest}"] = leaf[b]
         else:
             out[".".join(str(k) for k in keys)] = leaf
     return out

@@ -7,13 +7,14 @@
 
 This is how the limits in ``workloads/<cell>.json`` were set; the
 benchmark's own runs never run it.  For each seed it prints one JSON line
-with the readings of ``check.readings`` against the float32 reference:
+with the readings of ``check.readings`` against the float32 reference of
+the cell's configuration (its ``reference`` module):
 
 ``program``          the cell's timed path: ``program.Program`` built from
                      the seed and driven through its first steps, as
                      ``run.py`` does before its window;
 ``control``          the reference computed in float8
-                     (``reference.Reference(precision="fp8")``) in the
+                     (``Reference(precision="fp8")``) in the
                      program's place;
 ``fault:<name>``     the program with one of :data:`FAULTS` planted in its
                      step (``exchange`` only on more than one chip).
@@ -120,18 +121,18 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    from chipbench import check, reference, run, spec, traffic
+    from chipbench import check, run, spec, traffic
     from repro.launch.cache import enable_compile_cache
 
     root = pathlib.Path(args.root)
     cell = spec.load_cell(args.workload, root)
     devs = list(jax.devices()) if args.cpu else run.check_device(cell.chips)
     enable_compile_cache()
-    ref = reference.Reference(cell.model, cell.workload["train"],
-                              shards=cell.chips, dropless=cell.dropless)
+    reference = spec.module(cell.reference, root)
+    kw = dict(shards=cell.chips, dropless=cell.dropless)
+    ref = reference.Reference(cell.model, cell.workload["train"], **kw)
     fp8 = reference.Reference(cell.model, cell.workload["train"],
-                              shards=cell.chips, dropless=cell.dropless,
-                              precision="fp8")
+                              precision="fp8", **kw)
     steps = run.CHECK_STEPS
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()

@@ -14,7 +14,8 @@ warms up, and then either measures for ``--seconds`` seconds (``--trace
 1``: its per-layer metrics).  Both windows send about ``AHEAD_S``
 seconds of steps ahead of the one whose metrics the host waits for, so
 that the chip stays fed while the host stands still.  Then it frees the
-program's state, runs the plain reference over the same first steps and
+program's state, runs the configuration's plain reference (its
+``reference`` module, ``spec.module``) over the same first steps and
 prints, as the last line of standard output, one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
 ``breakdown`` when traced), the compared numbers coming last beside their
@@ -172,10 +173,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     """One run of cell ``name``; ``require_tpu=False`` skips the look for a
     chip (CPU tests at a small size)."""
     import jax
-    from chipbench import check, hlo, program, reference, spec, tracereduce
+    from chipbench import check, hlo, program, spec, tracereduce
     from repro.launch.cache import enable_compile_cache
 
     cell = spec.load_cell(name, root)
+    reference = spec.module(cell.reference, root)
+    counts = spec.module(cell.counts, root)
     devs = check_device(cell.chips) if require_tpu else jax.devices()
     print(f"device platform={devs[0].platform} kind={devs[0].device_kind} "
           f"count={len(devs)}", file=sys.stderr)
@@ -206,7 +209,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
             root=root, cell=cell, reduced=red, steps=TRACE_STEPS,
             window_s=red.window_s,
             memory=prog.compiled.memory_analysis(), hlo=text,
-            chips=cell.chips,
+            chips=cell.chips, counts=counts,
             peaks=lambda: spec.peaks(devs[0].device_kind, root))
         metrics = per_layer(cell, ctx)
         attempted, failed = TRACE_STEPS, 0

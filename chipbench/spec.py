@@ -8,10 +8,31 @@ own under ``chipbench/``, found from its name alone:
     traffic/<traffic>.json    a traffic mix, read by ``traffic.make_ring``
     workloads/<cell>.json     a cell: mesh, dispatch path, optimizer, limits
     metrics/<metric>.py       a per-layer metric: ``read(ctx) -> float | None``
+    <reference>.py            a configuration's plain reference
+    <counts>.py               a configuration's operation and byte counts
     peaks.json                peaks per ``device_kind``, with their source
 
-A later change adds a configuration, a traffic mix, a cell or a metric by
-adding files and entries, without editing any file here.
+A configuration file names its reference and counts modules under the
+keys ``"reference"`` and ``"counts"`` (default ``reference`` and
+``counts``), each a module ``chipbench/<name>.py`` found from its name
+alone (:func:`module`):
+
+* a reference module gives ``Reference(model, train, *, shards,
+  dropless, precision="f32")`` (``model``: the file's ``model`` numbers;
+  ``train``: the cell file's ``train``; ``precision="fp8"``: the
+  control), whose ``.train(seed, ring, steps, device)`` returns the
+  first steps' ``loss`` list and per-leaf ``grad_norm`` and ``change``
+  norms under the program's canonical leaf names, as ``check.readings``
+  compares them;
+* a counts module gives ``step_model_flops(model, batch, seq)``, the
+  model operations of one training step, which ``step_mfu`` reads.  Any
+  other function in it is read only by the metrics whose ``workloads``
+  list the cell.
+
+The readers get the cell's counts module as ``ctx.counts``.  So a later
+change adds a configuration of another architecture, a traffic mix, a
+cell or a metric by adding files and entries, without editing any file
+here.
 """
 from __future__ import annotations
 
@@ -20,6 +41,7 @@ import json
 import pathlib
 import re
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -82,6 +104,16 @@ class Cell:
     def limits(self) -> Dict[str, float]:
         return dict(self.workload.get("limits", {}))
 
+    @property
+    def reference(self) -> str:
+        """The name of the configuration's reference module."""
+        return self.config.get("reference", "reference")
+
+    @property
+    def counts(self) -> str:
+        """The name of the configuration's counts module."""
+        return self.config.get("counts", "counts")
+
 
 def load_benchmark(root: pathlib.Path = ROOT) -> Dict[str, Any]:
     return _read_json(pathlib.Path(root) / "BENCHMARK.json")
@@ -127,17 +159,30 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                 workload=workload, end_to_end=e2e, per_layer=per_layer)
 
 
+def _load(path: pathlib.Path, prefix: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        prefix + re.sub(r"\W", "_", path.stem), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, root: pathlib.Path = ROOT
                   ) -> Callable[[Any], Optional[float]]:
     """The ``read`` function of ``metrics/<name>.py``."""
     path = pathlib.Path(root) / BENCH_DIR / "metrics" / f"{_name(name)}.py"
     if not path.is_file():
         raise SpecError(f"no reader for metric {name!r} at {path}")
-    mod_name = "chipbench_metric_" + re.sub(r"\W", "_", name)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "chipbench_metric_").read
+
+
+def module(name: str, root: pathlib.Path = ROOT) -> ModuleType:
+    """The module ``chipbench/<name>.py``: a configuration's reference or
+    counts."""
+    path = pathlib.Path(root) / BENCH_DIR / f"{_name(name)}.py"
+    if not path.is_file():
+        raise SpecError(f"no module {name!r} at {path}")
+    return _load(path, "chipbench_module_")
 
 
 def peaks(device_kind: str, root: pathlib.Path = ROOT) -> Dict[str, float]:

@@ -1,7 +1,8 @@
 """Helpers and fixtures of the chip benchmark's CPU tests.
 
 ``tiny_root`` is a benchmark root of its own (a ``BENCHMARK.json`` and a
-``chipbench/`` of data files) whose cells run the paper's model at a
+``chipbench/`` of data files, with the default reference and counts
+modules) whose cells run the paper's model at a
 width a CPU test can hold: the sort path on one device, the GShard gate
 on the grouped Pallas path (interpreted) on one device, and the grouped
 path over four devices.  Its limits were set from CPU readings at this
@@ -31,7 +32,8 @@ def make_tiny_root(dst: pathlib.Path, limits=TINY_LIMITS) -> pathlib.Path:
     bench.mkdir(parents=True)
     for d in ("metrics", "traffic", "workloads", "configs"):
         shutil.copytree(ROOT / "chipbench" / d, bench / d)
-    shutil.copy(ROOT / "chipbench" / "peaks.json", bench / "peaks.json")
+    for f in ("peaks.json", "reference.py", "counts.py"):
+        shutil.copy(ROOT / "chipbench" / f, bench / f)
 
     def dump(rel, obj):
         (dst / rel).write_text(json.dumps(obj, indent=1))

@@ -17,7 +17,7 @@ def test_sound_run_is_correct(tiny_root, no_compile_cache, cell):
     assert list(res)[-1] == "compared"
 
 
-@pytest.mark.parametrize("cell", ["tiny-sort", "tiny-ep4"])
+@pytest.mark.parametrize("cell", ["tiny-sort", "tiny-ep4", "tiny-gshard"])
 def test_control_is_not_correct(tiny_root, cell):
     c = spec.load_cell(cell, tiny_root)
     kw = dict(shards=c.chips, dropless=c.dropless)

@@ -51,3 +51,10 @@ def test_grouped_matmul_work_per_chip():
     # gshard on one chip: 16384 routed rows, all 16 experts
     flops, _ = counts.grouped_matmul_work(_model(top_k=2), 8, 1024, 1)
     assert flops == pytest.approx(2 * 6 * 2 * 16384 * 2048 * 2048)
+
+
+def test_grouped_matmul_work_under_gshard_on_one_chip():
+    # top-2, 8 x 1024 tokens on one chip: 16384 rows, all 16 experts
+    flops, nbytes = counts.grouped_matmul_work(_model(top_k=2), 8, 1024, 1)
+    assert flops == pytest.approx(1.649e12, rel=1e-3)
+    assert nbytes == pytest.approx(3.221e9, rel=1e-3)

@@ -17,3 +17,14 @@ def test_fault_is_not_correct(tiny_root, no_compile_cache, fault):
         res = run.run(CELLS[fault], 1, 0.2, False, root=tiny_root,
                       require_tpu=False)
     assert res["correct"] is False, res["compared"]
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_fault_is_not_correct_on_the_dropless_path(tiny_root,
+                                                   no_compile_cache, fault):
+    """The faults a one-chip cell can have, on the GShard gate's dropless
+    grouped Pallas path too."""
+    with proof.FAULTS[fault]():
+        res = run.run("tiny-gshard", 1, 0.2, False, root=tiny_root,
+                      require_tpu=False)
+    assert res["correct"] is False, res["compared"]

@@ -141,3 +141,55 @@ def test_the_metrics_are_in_the_benchmark():
         assert m["moves"] == "tokens_per_s_per_chip"
         assert m["workloads"] == ["paper16e-switch-sort-1chip"]
     assert set(MS_METRICS.values()) | {"moe_exchange"} == set(scopes.SCOPES)
+
+
+# -- the grouped-matmul kernels' readers -----------------------------------
+
+EXPERTS = "/while/body/closed_call/moe_experts/"
+GROUPED = {
+    "fusion.20": "jit(train_step)/jvp()" + EXPERTS
+                 + "jit(_grouped_matmul_impl)/pallas_call",
+    "fusion.21": "jit(train_step)/transpose(jvp())" + EXPERTS
+                 + "jit(_grouped_matmul_impl)/pallas_call",
+    "fusion.22": "jit(train_step)/transpose(jvp())" + EXPERTS
+                 + "jit(_grouped_drhs_impl)/pallas_call",
+    # the block maps beside the kernel are not the kernel
+    "fusion.23": "jit(train_step)/transpose(jvp())" + EXPERTS
+                 + "jit(_grouped_matmul_impl)/jit(searchsorted)/gather",
+    "fusion.24": "jit(train_step)/jvp()/while/body/closed_call/moe_layout/"
+                 "jit(_gather_rows_impl)/pallas_call",
+}
+
+
+def _grouped_ctx(names, steps=2):
+    ops = [Op(0, 40, "fusion.20"), Op(40, 100, "fusion.21"),
+           Op(100, 180, "fusion.22"), Op(180, 190, "fusion.23"),
+           Op(190, 200, "fusion.24")]
+    red = Reduced(Trace({"/device:TPU:0": ops}, [Op(0, 200, "fetch")]),
+                  lo=0, hi=200, names=names)
+    # the paper's model under the GShard gate, 16384 assignments a step
+    paper = spec.load_cell("paper16e-switch-sort-1chip")
+    cell = dataclasses.replace(paper, config=dict(
+        paper.config, model=dict(paper.model, gate="gshard",
+                                 experts_per_token=2)))
+    return SimpleNamespace(reduced=red, steps=steps, cell=cell, chips=1,
+                           counts=spec.module(cell.counts),
+                           peaks=lambda: spec.peaks("TPU v5 lite"))
+
+
+def test_grouped_ffn_readers():
+    from chipbench import counts
+    ctx = _grouped_ctx(GROUPED)
+    ms = spec.metric_reader("grouped_ffn_ms", ROOT)(ctx)
+    assert ms == pytest.approx(1e3 * 180e-9 / 2)
+    flops, nbytes = counts.grouped_matmul_work(ctx.cell.model, 8, 1024, 1)
+    least = max(flops / 197e12, nbytes / 819e9)
+    pct = spec.metric_reader("grouped_ffn_roofline", ROOT)(ctx)
+    assert pct == pytest.approx(100 * least / (ms / 1e3))
+    assert least == pytest.approx(flops / 197e12)      # bound by operations
+
+
+def test_grouped_ffn_readers_read_none_without_the_kernels():
+    ctx = _grouped_ctx(META)
+    for name in ("grouped_ffn_ms", "grouped_ffn_roofline"):
+        assert spec.metric_reader(name, ROOT)(ctx) is None, name

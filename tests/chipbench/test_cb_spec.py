@@ -32,6 +32,12 @@ def test_benchmark_json_keys_and_names():
             assert m["unit"] == "%"
 
 
+def test_every_per_layer_metric_lists_its_cells():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["per_layer"]:
+        assert m.get("workloads") and set(m["workloads"]) <= cells, m["name"]
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_loads_and_matches_the_program(cell):
     c = spec.load_cell(cell)
@@ -42,6 +48,8 @@ def test_every_cell_loads_and_matches_the_program(cell):
     assert c.per_layer, "every cell reports a per-layer metric"
     program.model_config(c)         # raises where the file and program differ
     assert set(c.limits) == set(check.NUMBERS)
+    assert hasattr(spec.module(c.reference), "Reference")
+    assert hasattr(spec.module(c.counts), "step_model_flops")
 
 
 def _copy_root(tmp_path):
