@@ -125,6 +125,11 @@ def _leaf_norms(tree):
     return jax.tree_util.tree_map_with_path(norm, tree)
 
 
+@jax.jit
+def _take_rows(table, ids):
+    return table[ids]
+
+
 class NonFiniteRun(RuntimeError):
     """The trainer's non-finite streak reached its limit."""
 
@@ -205,6 +210,15 @@ class Program:
         step is ``(1 - b1)`` times the clipped gradient."""
         norms = jax.device_get(_leaf_norms(self.state.opt["m"]))
         return {k: float(v) for k, v in canonical_leaves(norms).items()}
+
+    def first_moment_rows(self, ids: np.ndarray) -> np.ndarray:
+        """Rows ``ids`` of AdamW's first moment of the embedding table, on
+        the host.  They are gathered on the device, with ``ids`` padded to
+        one step's tokens so that every seed gathers at the same shape."""
+        n = self.cell.batch * self.cell.seq
+        padded = np.pad(ids, (0, n - len(ids)), mode="edge")
+        rows = _take_rows(self.state.opt["m"]["embed"], padded)
+        return np.asarray(jax.device_get(rows))[:len(ids)]
 
     def free(self) -> None:
         """Drop the program's device state, so the reference has the chip."""

@@ -154,6 +154,8 @@ def main(argv=None) -> int:
             "cell": cell.name, "mode": mode, "seed": seed,
             "readings": {k: v["value"] for k, v in read.items()},
             "leaves": {k: v.get("leaf") for k, v in read.items()},
+            "embed_scale": read["embed_row_gap"]["scale"],
+            "embed_rows": read["embed_row_gap"]["rows"],
             "loss": got["loss"], "ref_loss": want["loss"],
             "grad_norm": got["grad_norm"], "ref_grad_norm": want["grad_norm"],
             "change": got["change"], "ref_change": want["change"],

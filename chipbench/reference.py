@@ -70,6 +70,11 @@ def _round_bwd(dtype, _, g):
 _round.defvjp(_round_fwd, _round_bwd)
 
 
+@jax.jit
+def _take_rows(table, ids):
+    return table[ids]
+
+
 class Reference:
     """The reference for one cell: ``model`` numbers (the config file),
     ``train`` settings (the cell file), token shards (chips) and whether
@@ -304,9 +309,14 @@ class Reference:
         """``steps`` steps from the seed's weights on ``batches[:steps]``,
         step ``s`` keyed ``fold_in(PRNGKey(seed), s)``.  Returns each
         step's loss, the per-leaf norms of the first step's clipped
-        gradient, and the per-leaf norms of the change of the parameters
-        after the last step."""
+        gradient, the rows of that gradient's embedding table at the ids
+        of the first batch's inputs (``embed_ids``, sorted, and
+        ``embed_rows``, read off the first moment as ``m / (1 - b1)``),
+        and the per-leaf norms of the change of the parameters after the
+        last step."""
         device = device or jax.devices()[0]
+        ids = np.unique(batches[0]["inputs"])
+        n = batches[0]["inputs"].size     # one gather shape for every seed
         with jax.default_device(device):
             p = self.init_params(seed)
             opt = {"m": {k: jnp.zeros_like(v) for k, v in p.items()},
@@ -321,8 +331,13 @@ class Reference:
                 losses.append(float(loss))
                 if s == 0:
                     grad_norms = {k: float(v) for k, v in norms.items()}
+                    rows = np.asarray(_take_rows(
+                        opt["m"]["embed"],
+                        np.pad(ids, (0, n - len(ids)), mode="edge")))
+                    rows = rows[:len(ids)] / (1 - self.t["b1"])
             del opt
             p0 = self.init_params(seed)     # drawn again: one copy at a time
             change = {k: float(jnp.sqrt(jnp.sum(jnp.square(p[k] - p0[k]))))
                       for k in p}
-        return {"loss": losses, "grad_norm": grad_norms, "change": change}
+        return {"loss": losses, "grad_norm": grad_norms, "change": change,
+                "embed_ids": ids, "embed_rows": rows}

@@ -8,7 +8,8 @@ asks for, or with the kernels in interpret mode), turns on the persistent
 compilation cache at its fixed path in the checkout, builds the cell's
 trainer (``program.Program``) from the seed and compiles its step once,
 drives the first steps through the step's own call (their losses,
-gradients and parameter change are what the correctness check reads),
+gradients, embedding rows and parameter change are what the correctness
+check reads),
 warms up, and then either measures for ``--seconds`` seconds (``--trace
 0``: the cell's end-to-end metrics) or traces a short window (``--trace
 1``: its per-layer metrics).  Both windows send about ``AHEAD_S``
@@ -79,23 +80,27 @@ def first_steps(prog) -> Tuple[Dict, float]:
     """Drive the program through its first steps, reading what the check
     compares.  Returns the readings and the seconds spent reading them
     (host copies of the state), which set-up does not count."""
+    import numpy as np
     b1 = prog.tcfg.b1
+    ids = np.unique(prog.ring[0]["inputs"])
     t = time.perf_counter()
     p0 = prog.params_to_host()
     spent = time.perf_counter() - t
-    losses, grad = [], None
+    losses, grad, rows = [], None, None
     for s in range(CHECK_STEPS):
         losses.append(prog.step(s)["loss"])
         if s == 0:
             t = time.perf_counter()
             grad = {k: v / (1 - b1)
                     for k, v in prog.first_moment_norms().items()}
+            rows = prog.first_moment_rows(ids) / (1 - b1)
             spent += time.perf_counter() - t
     t = time.perf_counter()
     change = _change_norms(prog, p0)
     del p0
     spent += time.perf_counter() - t
-    return {"loss": losses, "grad_norm": grad, "change": change}, spent
+    return {"loss": losses, "grad_norm": grad, "change": change,
+            "embed_ids": ids, "embed_rows": rows}, spent
 
 
 def ahead_steps(step_s: float) -> int:

@@ -21,9 +21,11 @@ alone (:func:`module`):
   dropless, precision="f32")`` (``model``: the file's ``model`` numbers;
   ``train``: the cell file's ``train``; ``precision="fp8"``: the
   control), whose ``.train(seed, ring, steps, device)`` returns the
-  first steps' ``loss`` list and per-leaf ``grad_norm`` and ``change``
-  norms under the program's canonical leaf names, as ``check.readings``
-  compares them;
+  first steps' ``loss`` list, per-leaf ``grad_norm`` and ``change``
+  norms under the program's canonical leaf names, and the first step's
+  clipped gradient of the embedding table at the ids of the first
+  batch's inputs (``embed_ids``, sorted and unique, and ``embed_rows``,
+  one row per id), as ``check.readings`` compares them;
 * a counts module gives ``step_model_flops(model, batch, seq)``, the
   model operations of one training step, which ``step_mfu`` reads.  Any
   other function in it is read only by the metrics whose ``workloads``

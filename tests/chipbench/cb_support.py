@@ -10,7 +10,9 @@ size (``proof.py --cpu``, seeds 1-4 and 2147483660): the program read
 loss gaps up to 1.33e-3, median gradient gaps up to 1.2e-3 and change
 gaps up to 5.0e-3; the float8 control read median gradient gaps of
 4.0e-3 and more, loss gaps of 2.1e-3 to 4.2e-3 on one device and change
-gaps of 6.9e-3 to 1.3e-2.
+gaps of 6.9e-3 to 1.3e-2.  The embedding rows: the program read
+``embed_row_gap`` 0.010 to 0.043 on the three cells, the control 0.178
+to 0.270, half of the batch left out 0.302 and more.
 """
 import json
 import pathlib
@@ -24,7 +26,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 TINY_LIMITS = {"loss_gap": 1.6e-3, "grad_norm_median_gap": 2.5e-3,
-               "change_gap": 6e-3}
+               "change_gap": 6e-3, "embed_row_gap": 0.09}
 
 
 def make_tiny_root(dst: pathlib.Path, limits=TINY_LIMITS) -> pathlib.Path:

@@ -139,7 +139,8 @@ def test_the_metrics_are_in_the_benchmark():
         m = entries[name]
         assert m["source"] == "device_trace"
         assert m["moves"] == "tokens_per_s_per_chip"
-        assert m["workloads"] == ["paper16e-switch-sort-1chip"]
+        assert m["workloads"] == ["paper16e-switch-sort-1chip",
+                                  "paper16e-gshard-grouped-1chip"]
     assert set(MS_METRICS.values()) | {"moe_exchange"} == set(scopes.SCOPES)
 
 
