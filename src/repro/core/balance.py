@@ -17,7 +17,7 @@ from repro.core.gating import GateOutput
 # every consumer stays in sync; duplicating the names at the shard_map
 # boundary produced an opaque pytree-mismatch error instead.
 METRIC_KEYS = ("load_balance_loss", "router_z_loss",
-               "expert_load_max", "dropped_share")
+               "expert_load_max", "dropped_share", "grouped_visit_share")
 
 
 def _masked_mean(x: jax.Array, valid: Optional[jax.Array],
@@ -80,6 +80,7 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
                valid: Optional[jax.Array] = None,
                axes: Tuple[str, ...] = (),
                dropped: Optional[jax.Array] = None,
+               visit_share: Optional[jax.Array] = None,
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Weighted aux-loss scalar + router metrics dict.
 
@@ -92,10 +93,14 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
     pmean is then an identity on them).  ``dropped`` — how many of this
     shard's valid assignments the dispatch dropped (capacity or the
     grouped-EP bound); ``None`` for a dropless dispatch.
+    ``visit_share`` — the grouped Pallas kernels' share of row-axis grid
+    steps that do work (``grouped_ffn.visit_share``); ``None`` on a path
+    that runs no grouped Pallas kernel.
 
     Metrics: ``expert_load_max`` is the busiest expert's share of all
     routed assignments (1/E when balanced, 1 when collapsed);
-    ``dropped_share`` is the dropped assignments over all routed ones.
+    ``dropped_share`` is the dropped assignments over all routed ones;
+    ``grouped_visit_share`` is ``visit_share``, a constant 0 without it.
     """
     E = gate.router_probs.shape[-1]
     lb = load_balance_loss(gate, valid, axes)
@@ -108,12 +113,15 @@ def aux_losses(cfg: MoEConfig, gate: GateOutput,
             jax.nn.one_hot(gate.expert_index, E, dtype=jnp.float32), axis=(0, 1))
     dropped = (jnp.zeros((), jnp.float32) if dropped is None
                else dropped.astype(jnp.float32))
+    visit_share = (jnp.zeros((), jnp.float32) if visit_share is None
+                   else visit_share.astype(jnp.float32))
     if axes:
         counts, dropped = lax.psum((counts, dropped), axes)
     total = jnp.maximum(jnp.sum(counts), 1.0)
     # zip(strict=True) raises even under ``python -O`` if a metric is
     # added to one side but not the other
     metrics = dict(zip(METRIC_KEYS,
-                       (lb, zl, jnp.max(counts) / total, dropped / total),
+                       (lb, zl, jnp.max(counts) / total, dropped / total,
+                        visit_share),
                        strict=True))
     return loss, metrics

@@ -191,12 +191,11 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
                 packed = xs0.reshape(1, B, d)          # the sorted buffer
                 send_counts = gplan.counts[None]       # (1, E)
                 dropped = None                         # B = T·K: dropless
-        with jax.named_scope("moe_gate"):
-            aux, metrics = balance.aux_losses(cfg, gate,
-                                              expert_counts=gplan.counts,
-                                              valid=valid, axes=pmean_axes,
-                                              dropped=dropped)
         n_src = packed.shape[0]
+        block_m = cfg.grouped_block_m or gffn.DEFAULT_BLOCK_M
+        # each window's grouped Pallas kernels' share of grid steps that
+        # do work (the routing counter grouped_visit_share)
+        visit_shares = []
         # Wire dtype for the exchange payloads (MegaScale-MoE).  A no-op
         # without expert parallelism: the exchange is the identity, so
         # there is no wire to quantize — pure-TP meshes keep full
@@ -246,14 +245,17 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
             else:
                 xs = recv.reshape(bc, d)
                 group_sizes = counts[0]
+            if cfg.use_pallas_gate:
+                with jax.named_scope("moe_gate"):
+                    visit_shares.append(gffn.visit_share(
+                        group_sizes, xs.shape[0], block_m))
             with jax.named_scope("moe_experts"):
                 ys = gffn.grouped_ffn(params,
                                       xs.astype(params["w_up"].dtype),
                                       group_sizes, act,
                                       use_pallas=cfg.use_pallas_gate,
                                       interpret=kops.INTERPRET,
-                                      block_m=(cfg.grouped_block_m
-                                               or gffn.DEFAULT_BLOCK_M))
+                                      block_m=block_m)
             if tp is not None:
                 # back to chunk layout, then reduce the f-contraction
                 # while scattering each TP rank its own rows (tiled:
@@ -315,6 +317,12 @@ def moe_block_local(cfg: MoEConfig, params: Dict[str, jax.Array], x: jax.Array,
             out = jnp.stack(outs, axis=1).reshape(n_src, B, d)
         else:
             out = compute(*exchange(packed, send_counts), B)
+        with jax.named_scope("moe_gate"):
+            aux, metrics = balance.aux_losses(
+                cfg, gate, expert_counts=gplan.counts, valid=valid,
+                axes=pmean_axes, dropped=dropped,
+                visit_share=(sum(visit_shares) / len(visit_shares)
+                             if visit_shares else None))
 
         with jax.named_scope("moe_layout"):
             if model_size > 1:

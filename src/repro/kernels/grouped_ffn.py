@@ -7,20 +7,28 @@ a grouped matmul (MegaBlocks' dMoE primitive).  Two implementations:
 
 ``ragged``  ``jax.lax.ragged_dot`` — XLA's native ragged primitive, used
             as the jnp reference path.
-``pallas``  Blocked kernel: grid ``(N/block_n, M/block_m, E)``; each
-            row-block visits each expert, but a ``pl.when`` predicate
-            skips (expert, block) pairs whose row ranges don't overlap —
-            with sorted rows a block overlaps ~1-2 experts, so the MXU
-            work is Σ_e ceil(n_e / block_m) tiles, not M/block_m · E.
-            The group offsets (and each block's first / last expert, which
-            clamp the weight index map so skipped steps fetch nothing new)
-            are scalar-prefetched into SMEM; rows outside the active
-            expert's range are masked before the dot.  The weights' lane
-            dim is tiled by ``block_n`` so a block fits the default scoped
-            VMEM at d = f = 2048.
+``pallas``  Blocked kernels over a visit list (megablox's ``gmm`` /
+            ``tgmm`` design).  The rows are cut into ``nb`` blocks of
+            ``block_m``; :func:`visit_list` turns the expert offsets into
+            the (expert, row block) pairs whose rows overlap, in row
+            order — each block once per expert it meets, so at most
+            ``V = nb + E - 1`` visits, against ``E · nb`` steps of a grid
+            over every pair.  The list (``group_of[V]``, ``block_of[V]``)
+            and its live count are scalar-prefetched into SMEM with the
+            offsets, and the kernel grid's row axis walks it: the index
+            maps read ``block_of[t]`` for the row blocks and
+            ``group_of[t]`` for the expert, so consecutive visits that
+            keep a block fetch nothing new.  The list is padded to ``V``
+            by repeating its last live visit; steps from the live count
+            on do nothing.  Rows outside the visited expert's range are
+            masked before the dot.  The weights' lane dim is tiled by
+            ``_lane_tile`` so a block fits the default scoped VMEM at
+            d = f = 2048.
 
 Rows past ``offsets[-1]`` (the virtual drop bucket's tail under token
-padding) belong to no expert and come out zero — matching ragged_dot.
+padding) belong to no expert and come out zero — matching ragged_dot:
+the forward's list hands them to the last expert, whose row mask then
+keeps none of them.
 
 Expert tensor parallelism needs no kernel variant: the kernels are
 shape-polymorphic in the weights' f dim, so the TP path simply passes
@@ -33,19 +41,24 @@ Pallas backward inherits this for free — dlhs sums R partials through
 the same psum (the psum_scatter transpose), drhs produces each rank's
 own (d, f/R) / (f/R, d) weight-gradient slice locally.
 
+Forward and dlhs share one kernel, grid ``(n_out / bn, V)``: the output
+block ``(block_of[t], j)`` stays resident while the experts that share
+its rows accumulate into it (f32), zeroed on the block's first visit.
 The ``custom_vjp`` backward is kernelized too (MegaBlocks trains the
 dMoE primitive in both directions) — no forward recompute, both
 gradients straight off the residuals:
 
-  dlhs  the SAME blocked grouped-matmul kernel with ``rhs`` transposed
-        on its last two dims (the ``transpose_rhs`` flag — a tile-level
-        transpose in-kernel, no HBM copy of the expert weights):
+  dlhs  the SAME kernel with ``rhs`` transposed on its last two dims
+        (the ``transpose_rhs`` flag — a tile-level transpose in-kernel,
+        no HBM copy of the expert weights):
         ``dlhs[seg_e] = g[seg_e] @ rhs[e]ᵀ``.
-  drhs  a segment-wise outer-product accumulation kernel: grid
-        ``(E, K/block_n, N/block_n, M/block_m)``, the scalar-prefetched
-        offsets predicate which row-blocks contribute to expert e's
-        ``(K, N)`` gradient tile, masked rows zeroed, partial products
-        accumulated in f32 (``drhs[e] = lhs[seg_e]ᵀ @ g[seg_e]``).
+  drhs  a segment-wise outer-product accumulation kernel, grid
+        ``(K / bk, N / bn, V)`` over its own visit list: the output tile
+        ``(group_of[t], a, b)`` stays resident across one expert's
+        consecutive visits, zeroed on the first, masked rows zeroed,
+        partial products accumulated in f32
+        (``drhs[e] = lhs[seg_e]ᵀ @ g[seg_e]``).  Its list visits an
+        expert with no rows once, so its gradient is an exact zero.
 """
 from __future__ import annotations
 
@@ -67,30 +80,76 @@ DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 512
 
 
-def _lane_tile(n: int) -> int:
-    """Largest multiple of 128 that divides ``n`` and is at most
-    ``DEFAULT_BLOCK_N``; ``n`` itself when it is that small or has no such
-    divisor (a block equal to the full dim always lowers)."""
-    if n <= DEFAULT_BLOCK_N:
+def _lane_tile(n: int, cap: int = DEFAULT_BLOCK_N) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most ``cap``;
+    ``n`` itself when it is that small or has no such divisor (a block
+    equal to the full dim always lowers)."""
+    if n <= cap:
         return n
-    for t in range(DEFAULT_BLOCK_N, 0, -128):
+    for t in range(cap - cap % 128, 0, -128):
         if n % t == 0:
             return t
     return n
 
 
-def _block_experts(offs: jax.Array, n_blocks: int, bm: int, M: int):
-    """First and last expert whose segment can meet each row block.
+def visit_list(offsets: jax.Array, n_blocks: int, block_m: int, *,
+               visit_empty: bool):
+    """The (expert, row block) pairs whose rows overlap, in row order.
 
-    The weight index maps clamp the expert grid axis into this range, so
-    the pipeline re-fetches an expert tile only when the expert actually
-    changes — not once per (row block, expert) grid step."""
-    E = offs.shape[0] - 1
-    first = jnp.arange(n_blocks, dtype=jnp.int32) * bm
-    last = jnp.minimum(first + bm, M) - 1
-    seg = lambda r: jnp.minimum(
-        jnp.searchsorted(offs[1:], r, side="right"), E - 1).astype(jnp.int32)
-    return seg(first), seg(last)
+    ``offsets`` (E + 1,) int32: expert e owns rows
+    ``[offsets[e], offsets[e + 1])`` of ``n_blocks · block_m``.  An expert
+    with rows visits each block its range meets; with ``visit_empty`` an
+    expert with none visits once the block its offset falls in (the last
+    block past the end), so a per-expert output is still written.  A
+    block two experts share is visited by both, one after the other.
+
+    Returns ``(group_of, block_of, live)``: int32 ``(V,)``, ``(V,)`` and
+    ``(1,)``, ``V = n_blocks + E - 1``.  Visits from ``live`` on repeat the
+    last live one.  Sorted segments meet at most ``n_blocks`` blocks plus
+    one more per boundary inside a block, and each empty expert takes a
+    boundary's place, so ``live <= V``.
+    """
+    E = offsets.shape[0] - 1
+    V = n_blocks + E - 1
+    lo, hi = offsets[:-1], offsets[1:]
+    n_vis = jnp.where(hi > lo, (hi - 1) // block_m - lo // block_m + 1,
+                      int(visit_empty))
+    first = jnp.minimum(lo // block_m, n_blocks - 1)
+    ends = jnp.cumsum(n_vis)
+    live = ends[-1]
+    t = jnp.minimum(jnp.arange(V, dtype=jnp.int32),
+                    jnp.maximum(live - 1, 0))
+    group = jnp.minimum(jnp.sum(t[:, None] >= ends[None, :], axis=1), E - 1)
+    block = first[group] + t - (ends[group] - n_vis[group])
+    return (group.astype(jnp.int32), block.astype(jnp.int32),
+            live.reshape(1).astype(jnp.int32))
+
+
+def _row_blocks(M: int, block_m: int):
+    """``(bm, nb)``: the row block (``block_m``, at most ``M``) and the
+    number of blocks over ``M`` rows padded to a multiple of it."""
+    bm = min(block_m, M)
+    return bm, -(-M // bm)
+
+
+def _fwd_visits(offs: jax.Array, nb: int, bm: int):
+    """The forward / dlhs visit list: the last expert's visits run on to
+    the end of the padded rows, so the tail past ``offs[-1]`` is visited
+    (and zeroed by its row mask); an expert with no rows is skipped."""
+    return visit_list(offs.at[-1].set(nb * bm), nb, bm, visit_empty=False)
+
+
+def visit_share(group_sizes: jax.Array, rows: int,
+                block_m: int = DEFAULT_BLOCK_M) -> jax.Array:
+    """Share of the forward / dlhs kernel grid's row-axis steps that do
+    work (live visits over ``V``) for ``rows`` rows in expert segments of
+    ``group_sizes``: 1 when every step visits a pair; a grid over every
+    (expert, row block) pair would read about ``1 / E``."""
+    bm, nb = _row_blocks(rows, block_m)
+    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(group_sizes).astype(jnp.int32)])
+    live = _fwd_visits(offs, nb, bm)[2][0]
+    return live.astype(jnp.float32) / (nb + group_sizes.shape[0] - 1)
 
 
 def _row_mask(offs_ref, e, row0, block_m):
@@ -98,22 +157,31 @@ def _row_mask(offs_ref, e, row0, block_m):
     return (rows >= offs_ref[e]) & (rows < offs_ref[e + 1])
 
 
-def _overlaps(offs_ref, e, row0, block_m):
-    return jnp.logical_and(offs_ref[e + 1] > row0,
-                           offs_ref[e] < row0 + block_m)
+def _does_work(t, live_ref, offs_ref, e, row0, block_m):
+    """A live visit whose expert has rows in ``[row0, row0 + block_m)``."""
+    return jnp.logical_and(
+        t < live_ref[0],
+        jnp.maximum(offs_ref[e], row0)
+        < jnp.minimum(offs_ref[e + 1], row0 + block_m))
 
 
-def _grouped_matmul_kernel(offs_ref, elo_ref, ehi_ref, lhs_ref, rhs_ref,
-                           out_ref, *, block_m: int, transpose_rhs: bool):
-    i, e = pl.program_id(1), pl.program_id(2)
+def _first_visit(ref, t):
+    """Whether ``ref[t]`` differs from the previous visit's (or t is 0)."""
+    return jnp.logical_or(t == 0, ref[t] != ref[jnp.maximum(t - 1, 0)])
 
-    @pl.when(e == 0)
+
+def _grouped_matmul_kernel(offs_ref, grp_ref, blk_ref, live_ref, lhs_ref,
+                           rhs_ref, out_ref, *, block_m: int,
+                           transpose_rhs: bool):
+    t = pl.program_id(1)
+
+    @pl.when(_first_visit(blk_ref, t))
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    row0 = i * block_m
+    e, row0 = grp_ref[t], blk_ref[t] * block_m
 
-    @pl.when(_overlaps(offs_ref, e, row0, block_m))
+    @pl.when(_does_work(t, live_ref, offs_ref, e, row0, block_m))
     def _tile():
         x = jnp.where(_row_mask(offs_ref, e, row0, block_m), lhs_ref[...], 0)
         # transpose_rhs serves the dlhs backward: the weight tile is
@@ -135,32 +203,33 @@ def _grouped_matmul_impl(lhs: jax.Array, rhs: jax.Array, offsets: jax.Array,
     """y[seg_e] = lhs[seg_e] @ rhs[e] — or @ rhs[e].T with
     ``transpose_rhs`` (the dlhs backward; lhs is then (M, N) → (M, K)).
 
-    Grid (out tiles, row blocks, E): each (row block, out tile) output
-    block stays resident while the experts accumulate into it."""
+    Grid (out tiles, visits): each (row block, out tile) output block
+    stays resident while the experts that share the block accumulate
+    into it."""
     M, C = lhs.shape
     E, K, N = rhs.shape
     n_out = K if transpose_rhs else N
-    bm = min(block_m, M)
+    bm, nb = _row_blocks(M, block_m)
     bn = _lane_tile(n_out)
-    pad = (-M) % bm
+    pad = nb * bm - M
     if pad:
         lhs = jnp.concatenate([lhs, jnp.zeros((pad, C), lhs.dtype)])
-    nb = (M + pad) // bm
     offs = offsets.astype(jnp.int32)
-    e_lo, e_hi = _block_experts(offs, nb, bm, M + pad)
+    grp, blk, live = _fwd_visits(offs, nb, bm)
 
-    def w_map(j, i, e, offs, lo, hi):
-        ee = jnp.clip(e, lo[i], hi[i])
-        return (ee, j, 0) if transpose_rhs else (ee, 0, j)
+    def w_map(j, t, offs, grp, blk, live):
+        return (grp[t], j, 0) if transpose_rhs else (grp[t], 0, j)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_out // bn, nb, E),
+        num_scalar_prefetch=4,
+        grid=(n_out // bn, nb + E - 1),
         in_specs=[
-            pl.BlockSpec((bm, C), lambda j, i, e, *_: (i, 0)),
+            pl.BlockSpec((bm, C), lambda j, t, offs, grp, blk, live:
+                         (blk[t], 0)),
             pl.BlockSpec((1, bn, N) if transpose_rhs else (1, K, bn), w_map),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda j, i, e, *_: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda j, t, offs, grp, blk, live:
+                               (blk[t], j)),
     )
     out = pl.pallas_call(
         functools.partial(_grouped_matmul_kernel, block_m=bm,
@@ -168,21 +237,36 @@ def _grouped_matmul_impl(lhs: jax.Array, rhs: jax.Array, offsets: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M + pad, n_out), jnp.float32),
         interpret=interpret,
-    )(offs, e_lo, e_hi, lhs, rhs)
+    )(offs, grp, blk, live, lhs, rhs)
     return (out[:M] if pad else out).astype(lhs.dtype)
 
 
-def _grouped_drhs_kernel(offs_ref, blo_ref, bhi_ref, lhs_ref, g_ref, out_ref,
-                         *, block_m: int):
-    e, i = pl.program_id(0), pl.program_id(3)
+# drhs tiles: its row block is the contraction dim, so a deeper one means
+# fewer grid steps for the same MXU work.  A (1024, 1024) f32 output tile
+# is 4 MiB, double-buffered 8 MiB, and fits the default scoped VMEM with
+# (256, 1024) bf16 operand blocks.
+DRHS_BLOCK_M = 256
+DRHS_BLOCK_N = 1024
 
-    @pl.when(i == 0)
+
+def _drhs_tiles(M: int, K: int, N: int, block_m: int):
+    """``(bm, bk, bn)`` of the drhs kernel: its row block (the contraction
+    dim, at least ``block_m``) and the lane tiles of its (K, N) output."""
+    return (min(max(block_m, DRHS_BLOCK_M), M),
+            _lane_tile(K, DRHS_BLOCK_N), _lane_tile(N, DRHS_BLOCK_N))
+
+
+def _grouped_drhs_kernel(offs_ref, grp_ref, blk_ref, live_ref, lhs_ref,
+                         g_ref, out_ref, *, block_m: int):
+    t = pl.program_id(2)
+
+    @pl.when(_first_visit(grp_ref, t))
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    row0 = i * block_m
+    e, row0 = grp_ref[t], blk_ref[t] * block_m
 
-    @pl.when(_overlaps(offs_ref, e, row0, block_m))
+    @pl.when(_does_work(t, live_ref, offs_ref, e, row0, block_m))
     def _tile():
         # masking ONE operand suffices: rows outside [lo, hi) — including
         # the virtual drop bucket's tail — contribute a zero outer product
@@ -197,42 +281,38 @@ def _grouped_drhs_impl(lhs: jax.Array, g: jax.Array, offsets: jax.Array,
                        block_m: int = DEFAULT_BLOCK_M) -> jax.Array:
     """drhs (E, K, N) f32 with drhs[e] = lhs[seg_e].T @ g[seg_e].
 
-    Grid (E, K tiles, N tiles, M/block_m): expert-major so each expert's
-    (bk, bn) output tile stays resident while its row-blocks accumulate
-    into it; the row-block index maps clamp into the expert's own blocks
-    (no fetch of rows it never reads), and the offsets predicate skips
-    blocks outside [offs[e], offs[e+1])."""
+    Grid (K tiles, N tiles, visits): each expert's (bk, bn) output tile
+    stays resident while its row blocks accumulate into it; an expert
+    with no rows is visited once and left at zero."""
     M, K = lhs.shape
     _, N = g.shape
     E = offsets.shape[0] - 1
-    bm = min(block_m, M)
-    bk, bn = _lane_tile(K), _lane_tile(N)
-    pad = (-M) % bm
+    bm, bk, bn = _drhs_tiles(M, K, N, block_m)
+    nb = -(-M // bm)
+    pad = nb * bm - M
     if pad:
         lhs = jnp.concatenate([lhs, jnp.zeros((pad, K), lhs.dtype)])
         g = jnp.concatenate([g, jnp.zeros((pad, N), g.dtype)])
-    nb = (M + pad) // bm
     offs = offsets.astype(jnp.int32)
-    b_lo = jnp.minimum(offs[:-1] // bm, nb - 1)
-    b_hi = jnp.minimum(jnp.maximum(offs[1:] - 1, offs[:-1]) // bm, nb - 1)
-    row = lambda e, i, lo, hi: jnp.clip(i, lo[e], hi[e])
+    grp, blk, live = visit_list(offs, nb, bm, visit_empty=True)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(E, K // bk, N // bn, nb),
+        num_scalar_prefetch=4,
+        grid=(K // bk, N // bn, nb + E - 1),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda e, a, b, i, offs, lo, hi:
-                         (row(e, i, lo, hi), a)),
-            pl.BlockSpec((bm, bn), lambda e, a, b, i, offs, lo, hi:
-                         (row(e, i, lo, hi), b)),
+            pl.BlockSpec((bm, bk), lambda a, b, t, offs, grp, blk, live:
+                         (blk[t], a)),
+            pl.BlockSpec((bm, bn), lambda a, b, t, offs, grp, blk, live:
+                         (blk[t], b)),
         ],
-        out_specs=pl.BlockSpec((1, bk, bn), lambda e, a, b, i, *_: (e, a, b)),
+        out_specs=pl.BlockSpec((1, bk, bn), lambda a, b, t, offs, grp, blk,
+                               live: (grp[t], a, b)),
     )
     return pl.pallas_call(
         functools.partial(_grouped_drhs_kernel, block_m=bm),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
         interpret=interpret,
-    )(offs, b_lo.astype(jnp.int32), b_hi.astype(jnp.int32), lhs, g)
+    )(offs, grp, blk, live, lhs, g)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

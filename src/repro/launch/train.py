@@ -29,7 +29,9 @@ metrics to the host, which waits for the device) and
 ``PROFILE_STEPS`` steps after the first ``PROFILE_SKIP``, where those
 spans sit on one clock with the device's ops.  An MoE model's log line
 and history carry the routing counters ``expert_load_ratio`` (busiest
-expert over the mean) and ``dropped_share`` (assignments dropped).
+expert over the mean), ``dropped_share`` (assignments dropped) and
+``grouped_visit_share`` (the grouped Pallas kernels' grid steps that do
+work; 0 on other paths).
 """
 from __future__ import annotations
 
@@ -196,7 +198,8 @@ def _log_line(s: int, m: dict, tput: float) -> str:
             f"{m['nonfinite_streak']:.0f}")
     if "expert_load_ratio" in m:
         line += (f" load {m['expert_load_ratio']:.3f} "
-                 f"drop {m['dropped_share']:.4f}")
+                 f"drop {m['dropped_share']:.4f} "
+                 f"visit {m['grouped_visit_share']:.3f}")
     return line + f" tok/s {tput:,.0f}"
 
 

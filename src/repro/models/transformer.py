@@ -353,11 +353,13 @@ def _embed_inputs(params, cfg: ModelConfig, inputs: jax.Array, dtype, mesh=None)
 
 def _router_stats(routers, num_experts: int):
     """One block stack's MoE metrics → ``(expert_load_ratio, dropped
-    share summed over the layers)``: the busiest expert's assignments
-    over the mean, largest over the layers."""
+    share summed over the layers, grouped visit share summed over the
+    layers)``: the busiest expert's assignments over the mean, largest
+    over the layers."""
     ratio = jnp.max(jnp.stack([r["expert_load_max"] for r in routers]))
     dropped = sum(r["dropped_share"] for r in routers)
-    return ratio * num_experts, dropped
+    visits = sum(r["grouped_visit_share"] for r in routers)
+    return ratio * num_experts, dropped, visits
 
 
 def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
@@ -373,8 +375,10 @@ def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
     ``router_metrics`` (a model with MoE blocks) a fourth item, the
     routing counters: ``expert_load_ratio`` (the busiest expert's
     assignments over the mean, largest over the MoE layers: 1 balanced,
-    E collapsed) and ``dropped_share`` (assignments dropped by capacity
-    or the grouped-EP bound over all routed ones, over the MoE layers).
+    E collapsed), ``dropped_share`` (assignments dropped by capacity
+    or the grouped-EP bound over all routed ones, over the MoE layers) and
+    ``grouped_visit_share`` (the grouped Pallas kernels' share of grid
+    steps that do work, over the MoE layers; 0 on other paths).
     """
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("embed"):
@@ -423,10 +427,12 @@ def forward(params: Dict[str, Any], inputs: jax.Array, cfg: ModelConfig, *,
         x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if not router_metrics:
         return x, aux, out_caches
-    ratio, dropped = stats
+    ratio, dropped, visits = stats
     n_moe = cfg.num_super_blocks * cfg.block_pattern.count("moe")
-    return x, aux, out_caches, {"expert_load_ratio": jnp.max(ratio),
-                                "dropped_share": jnp.sum(dropped) / n_moe}
+    return x, aux, out_caches, {
+        "expert_load_ratio": jnp.max(ratio),
+        "dropped_share": jnp.sum(dropped) / n_moe,
+        "grouped_visit_share": jnp.sum(visits) / n_moe}
 
 
 def head_weight(params, cfg: ModelConfig) -> jax.Array:

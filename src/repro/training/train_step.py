@@ -206,8 +206,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
     split on the batch axis and accumulated via scan.
 
     A model with MoE blocks adds the routing counters to the metrics:
-    ``expert_load_ratio`` (largest over the microbatches) and
-    ``dropped_share`` (their mean), see ``transformer.forward``.  The
+    ``expert_load_ratio`` (largest over the microbatches),
+    ``dropped_share`` and ``grouped_visit_share`` (their means), see
+    ``transformer.forward``.  The
     cross-entropy runs under the ``head_loss`` named scope and the
     guard, clipping and AdamW under ``optimizer``, so their device ops
     say so in the compiled step's op metadata.
@@ -267,9 +268,12 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
             loss, ce, aux = loss / mbs, ce / mbs, aux / mbs
             if routed:
                 # over the microbatches: the busiest load, the mean drops
+                # and visit share
                 router = {
                     "expert_load_ratio": jnp.max(router["expert_load_ratio"]),
-                    "dropped_share": jnp.mean(router["dropped_share"])}
+                    "dropped_share": jnp.mean(router["dropped_share"]),
+                    "grouped_visit_share": jnp.mean(
+                        router["grouped_visit_share"])}
 
         grads = faults_mod.apply_traced(faults, "train.grads", state.step,
                                         grads)

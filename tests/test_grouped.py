@@ -5,14 +5,19 @@ the sort path when capacity is non-binding, zero drops when it is, and
 bit-identity of the blocked layout kernels against the jnp oracles
 across block sizes including ragged tails.
 """
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import analysis
 from repro.core import capacity, gating, layout, moe
 from repro.core.config import MoEConfig
+from repro.kernels import grouped_ffn as gffn
 from repro.kernels import ref
 from repro.kernels.grouped_ffn import grouped_ffn, grouped_matmul
 from repro.kernels.layout_transform import gather_rows, scatter_add_rows
@@ -521,20 +526,105 @@ def _small_ints(key, shape):
     return jax.random.randint(key, shape, -4, 5).astype(jnp.float32)
 
 
-@pytest.mark.parametrize("M,K,N,E,bm,tail", [
+@pytest.fixture
+def nan_outputs(monkeypatch):
+    """Every grouped kernel's output starts as NaN, aliased in through
+    ``input_output_aliases``: a block the kernel never writes (a tail row
+    block, an empty expert's gradient) then fails the comparison instead
+    of reading the interpreter's zeros."""
+    def pallas_call(kernel, *, grid_spec, out_shape, **kw):
+        gs = grid_spec
+        spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=gs.num_scalar_prefetch, grid=gs.grid,
+            in_specs=[*gs.in_specs, gs.out_specs], out_specs=gs.out_specs)
+
+        def call(*args):
+            fill = jnp.full(out_shape.shape, jnp.nan, out_shape.dtype)
+            return pl.pallas_call(
+                lambda *refs: kernel(*refs[:-2], refs[-1]), grid_spec=spec,
+                out_shape=out_shape, input_output_aliases={len(args): 0},
+                **kw)(*args, fill)
+        return call
+
+    monkeypatch.setattr(gffn, "pl", types.SimpleNamespace(
+        **{**vars(pl), "pallas_call": pallas_call}))
+    impls = (gffn._grouped_matmul_impl, gffn._grouped_drhs_impl)
+    for f in impls:
+        f.clear_cache()
+    yield
+    for f in impls:
+        f.clear_cache()
+
+
+@pytest.mark.parametrize("M,bm,sizes", [
+    (64, 16, (0, 20, 30, 14)),        # empty first expert
+    (64, 16, (20, 0, 30, 14)),        # empty middle expert
+    (64, 16, (20, 30, 14, 0)),        # empty last expert
+    (64, 16, (0, 64, 0, 0)),          # all rows in one expert
+    (100, 16, (7, 13, 9, 21, 50)),    # M not a multiple of block_m
+    (40, 128, (10, 0, 25, 5)),        # M < block_m
+    (64, 16, (5, 20, 0, 9)),          # tail rows past offsets[-1]
+    (64, 16, (0, 0, 0, 0)),           # every row in the tail
+])
+@pytest.mark.parametrize("visit_empty", [False, True])
+def test_visit_list_covers_each_overlap_once_in_row_order(M, bm, sizes,
+                                                          visit_empty):
+    E = len(sizes)
+    bm_, nb = gffn._row_blocks(M, bm)
+    offs = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int32)
+    grp, blk, live = (np.asarray(a) for a in gffn.visit_list(
+        jnp.asarray(offs), nb, bm_, visit_empty=visit_empty))
+    V, live = nb + E - 1, int(live[0])
+    assert grp.shape == blk.shape == (V,) and live <= V
+    expect = []
+    for e in range(E):
+        lo, hi = offs[e], offs[e + 1]
+        if hi > lo:
+            expect += [(e, b) for b in range(nb)
+                       if max(lo, b * bm_) < min(hi, (b + 1) * bm_)]
+        elif visit_empty:
+            expect.append((e, min(lo // bm_, nb - 1)))
+    # every overlapping pair once, and in row order
+    assert list(zip(grp[:live].tolist(), blk[:live].tolist())) == expect
+    assert (np.diff(blk) >= 0).all()
+    # padding repeats the last live visit
+    if live:
+        assert (grp[live:] == grp[live - 1]).all()
+        assert (blk[live:] == blk[live - 1]).all()
+    # the forward's list also visits the tail, so every row block is zeroed
+    fwd = [np.asarray(a) for a in gffn._fwd_visits(jnp.asarray(offs), nb,
+                                                    bm_)]
+    assert set(fwd[1][:int(fwd[2][0])].tolist()) == set(range(nb))
+    assert int(fwd[2][0]) <= V
+
+
+@pytest.mark.parametrize("M,K,N,groups,bm,tail", [
     (64, 16, 24, 4, 16, 0),
     (100, 8, 8, 3, 128, 7),     # virtual-bucket tail rows → zeros
     (37, 32, 16, 5, 8, 3),      # ragged blocks + tail
     (64, 1024, 1536, 4, 16, 5),  # 2 x 3 lane tiles of 512 (K != N)
+    # row block 3 lies wholly in the tail
+    pytest.param(64, 16, 24, (0, 20, 24, 0), 16, 20, id="empty_first_last"),
+    pytest.param(64, 16, 24, (0, 64, 0), 16, 0, id="one_expert_all_rows"),
+    pytest.param(64, 16, 24, (7, 13, 9, 21, 11, 3), 16, 0,
+                 id="boundaries_inside_blocks"),
+    pytest.param(520, 128, 256, 5, 256, 9, id="block_m_over_lane_tile"),
 ])
-def test_grouped_matmul_matches_ragged_dot(M, K, N, E, bm, tail):
+def test_grouped_matmul_matches_ragged_dot(M, K, N, groups, bm, tail,
+                                           nan_outputs):
+    """``groups``: the number of experts, cut at random, or their sizes.
+    Outputs start as NaN (``nan_outputs``), so every block is written."""
     k1, k2 = jax.random.split(jax.random.PRNGKey(M), 2)
+    total = M - tail
+    if isinstance(groups, int):
+        cuts = np.sort(np.random.RandomState(0).randint(0, total + 1,
+                                                        groups - 1))
+        groups = np.diff(np.concatenate([[0], cuts, [total]]))
+    assert sum(groups) == total
+    E = len(groups)
+    sizes = jnp.array(groups, jnp.int32)
     lhs = _small_ints(k1, (M, K))
     rhs = _small_ints(k2, (E, K, N))
-    total = M - tail
-    cuts = np.sort(np.random.RandomState(0).randint(0, total + 1, E - 1))
-    sizes = jnp.array(np.diff(np.concatenate([[0], cuts, [total]])),
-                      jnp.int32)
     out = grouped_matmul(lhs, rhs, sizes, True, bm)
     expect = jax.lax.ragged_dot(lhs, rhs, sizes)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -548,6 +638,26 @@ def test_grouped_matmul_matches_ragged_dot(M, K, N, E, bm, tail):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_grouped_grids_walk_visit_lists():
+    """Forward, dlhs and drhs: each kernel grid's row axis walks a visit
+    list of nb + E - 1 steps, not one step per (expert, row block)."""
+    M, K, N, E, bm = 100, 8, 16, 4, 16
+    lhs = jax.random.normal(RNG, (M, K))
+    rhs = jax.random.normal(RNG, (E, K, N))
+    sizes = jnp.array([30, 0, 41, 22], jnp.int32)
+    g = analysis.trace_graph(
+        jax.grad(lambda l, r: jnp.sum(grouped_matmul(l, r, sizes, True,
+                                                     bm) ** 2), (0, 1)),
+        lhs, rhs)
+    grids = sorted(s.eqn.params["grid_mapping"].grid
+                   for s in g.find("pallas_call"))
+    nb = -(-M // bm)
+    nb_drhs = -(-M // gffn._drhs_tiles(M, K, N, bm)[0])
+    assert grids == sorted([(1, nb + E - 1), (1, nb + E - 1),
+                            (1, 1, nb_drhs + E - 1)]), grids
+    assert all(grid[-1] < E * nb for grid in grids)
 
 
 def test_grouped_ffn_paths_agree():
@@ -577,16 +687,22 @@ def test_grouped_ffn_paths_agree():
     (96, 16, 24, (30, 20, 0, 25, 15)),
     # K = 1024, N = 1536: dlhs over 2 and drhs over 2 x 3 lane tiles of 512
     (64, 1024, 1536, (20, 0, 26, 12)),
-], ids=["narrow", "lane_tiled"])
+    (64, 16, 24, (0, 20, 20, 0)),     # at bm 16, block 3 is all tail
+    (64, 16, 24, (0, 0, 58, 0)),
+    (64, 16, 24, (7, 13, 9, 21, 11)),
+], ids=["narrow", "lane_tiled", "empty_first_last", "one_expert",
+        "boundaries_inside_blocks"])
 def test_grouped_bwd_matches_ragged_vjp(M, K, N, group_sizes, dtype, rtol,
-                                        atol, bm):
-    """Explicit-cotangent VJP equality, with an EMPTY expert segment and
-    6 drop-bucket tail rows past offsets[-1]."""
+                                        atol, bm, nan_outputs):
+    """Explicit-cotangent VJP equality, with drop-bucket tail rows past
+    offsets[-1] and EMPTY expert segments (first, middle, last) or every
+    expert boundary inside a row block.  Outputs start as NaN
+    (``nan_outputs``), so a block no visit writes fails."""
     E, total = len(group_sizes), sum(group_sizes)
-    empty = group_sizes.index(0)
+    empty = [e for e, n in enumerate(group_sizes) if n == 0]
     lhs = _small_ints(RNG, (M, K)).astype(dtype)
     rhs = _small_ints(jax.random.PRNGKey(1), (E, K, N)).astype(dtype)
-    sizes = jnp.array(group_sizes, jnp.int32)              # Σ = M - 6
+    sizes = jnp.array(group_sizes, jnp.int32)              # Σ < M
     g = _small_ints(jax.random.PRNGKey(2), (M, N)).astype(dtype)
 
     _, vjp_p = jax.vjp(lambda l, r: grouped_matmul(l, r, sizes, True, bm),
@@ -604,7 +720,7 @@ def test_grouped_bwd_matches_ragged_vjp(M, K, N, group_sizes, dtype, rtol,
     # tail rows produce zero output, so their lhs gradient is zero
     assert np.allclose(np.asarray(dl_p, np.float32)[total:], 0.0)
     # an empty expert's weight gradient is exactly zero
-    assert np.allclose(np.asarray(dr_p, np.float32)[empty], 0.0)
+    assert (np.asarray(dr_p, np.float32)[empty] == 0.0).all()
 
 
 def test_grouped_bwd_is_pallas_not_ragged_recompute():

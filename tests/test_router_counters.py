@@ -1,6 +1,8 @@
 """Routing counters: ``dropped_share`` (assignments dropped over routed)
 and ``expert_load_ratio`` (busiest expert over the mean), from the
-dispatch plan's counts, for the MoE layer and through the train step."""
+dispatch plan's counts, and ``grouped_visit_share`` (the grouped Pallas
+kernels' grid steps that do work), for the MoE layer and through the
+train step."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -67,6 +69,22 @@ def test_grouped_path_is_dropless_and_agrees_on_the_load():
     assert grouped["expert_load_max"] == sort["expert_load_max"]
 
 
+@pytest.mark.parametrize("dispatch,pallas,share", [
+    # 64 rows in four experts of 16, row blocks of 24 (padded to 72):
+    # expert 0 meets block 0, expert 1 blocks 0 and 1, expert 2 block 1,
+    # expert 3 block 2 and the padding: 5 visits of nb + E - 1 = 6 steps
+    ("grouped", True, 5 / 6),
+    ("grouped", False, 0.0),    # lax.ragged_dot: no grouped Pallas kernel
+    ("sort", True, 0.0),
+    ("sort", False, 0.0),
+    ("dense", False, 0.0),
+])
+def test_grouped_visit_share(dispatch, pallas, share):
+    cfg = _cfg(dispatch, use_pallas_gate=pallas, grouped_block_m=24)
+    assert _local(cfg, *_uniform())["grouped_visit_share"] == (
+        pytest.approx(share))
+
+
 def test_grouped_ep_bound_drops_like_capacity(mesh_ep4):
     """Over four expert-parallel ranks, each rank's 16 tokens all want
     expert 0: the sort path keeps C = 8 of them, the grouped exchange's
@@ -93,10 +111,12 @@ def test_train_step_reports_the_counters(mesh1, microbatches):
     n = cfg.moe.num_experts
     assert 1.0 <= m["expert_load_ratio"] <= n
     assert 0.0 <= m["dropped_share"] < 1.0
+    assert 0.0 <= m["grouped_visit_share"] <= 1.0
 
 
 def test_dense_model_has_no_counters(mesh1):
     cfg = configs.smoke_config("starcoder2-3b")
     tr = train.build(cfg, TrainConfig(), mesh1, batch=2, seq=16)
     m = tr.fetch(tr.dispatch(tr.state, 0)[1])
-    assert "expert_load_ratio" not in m and "dropped_share" not in m
+    assert not {"expert_load_ratio", "dropped_share",
+                "grouped_visit_share"} & set(m)
